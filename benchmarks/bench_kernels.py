@@ -7,7 +7,7 @@ MeshNet forward per paper model. ``bench_traffic`` prints the modeled HBM
 bytes per forward at the paper's 256^3 volume for every registered
 executor (telemetry/traffic.py) — the measurement behind EXPERIMENTS.md
 §Perf H1 (per-layer fusion) and §Perf H9 (depth-first tiling: megakernel
->= 5x under pallas_fused).
+>= 1.3x under pallas_fused).
 
 Every row is (name, us_per_call, hbm_bytes_modeled, note); bytes are None
 where no traffic model applies (training-side oracles).
@@ -61,7 +61,7 @@ def bench() -> list[Row]:
     ref_fn = jax.jit(lambda x, w, b: ref.dilated_conv3d(x, w, b, dilation=8))
     rows.append(("dilated_conv3d_xla_ref_32cube", _time(ref_fn, x, w, b), None, "oracle"))
     pal_fn = jax.jit(
-        lambda x, w, b: ops.dilated_conv3d(x, w, b, dilation=8, interpret=True)
+        lambda x, w, b: ops.dilated_conv3d(x, w, b, dilation=8)
     )
     rows.append(("dilated_conv3d_pallas_interp_32cube", _time(pal_fn, x, w, b), conv_b, "interpret-mode (correctness path; compiled Mosaic on TPU)"))
 
@@ -71,7 +71,7 @@ def bench() -> list[Row]:
 
     dice_b = 2 * 64**3 * 4  # pred + truth reads; counts are negligible
     rows.append(("dice_xla_ref_64cube", _time(jax.jit(lambda a, b: losses.dice_score(a, b, 3)), pred, truth), None, "oracle"))
-    rows.append(("dice_pallas_interp_64cube", _time(lambda a, b: ops.dice(a, b, 3, interpret=True), pred, truth), dice_b, "interpret-mode"))
+    rows.append(("dice_pallas_interp_64cube", _time(lambda a, b: ops.dice(a, b, 3), pred, truth), dice_b, "interpret-mode"))
 
     cfg = MeshNetConfig()
     p = meshnet.init(KEY, cfg)

@@ -9,7 +9,7 @@ Sections:
                   each reduced precision policy ("@bf16"/"@int8w" keys)
   [traffic]       modeled HBM bytes per forward at the paper's 256^3
                   volume for every registered executor (EXPERIMENTS.md
-                  §Perf H9: megakernel >= 5x under pallas_fused) and
+                  §Perf H9: megakernel >= 1.3x under pallas_fused) and
                   every precision policy (H11: int8w <= 0.4x, bf16 <=
                   0.55x of fp32 on the megakernel; fp32 keys stay
                   un-suffixed so the gate diffs like-for-like)
@@ -284,6 +284,9 @@ def main(argv: list[str] | None = None, json_path: str = JSON_PATH) -> None:
         del args[i : i + 2]
         emit_json = True
     wanted = args or list(SECTIONS)
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()  # one process: no section starts a child
     trajectory: dict[str, list] = {}
     for name in wanted:
         rows = SECTIONS[name]()

@@ -55,12 +55,6 @@ from repro.core import meshnet
 from repro.core.meshnet import MeshNetConfig
 from repro.kernels import ops, quantize
 
-# jax.shard_map landed after 0.4.x; fall back to the experimental home.
-try:  # pragma: no cover - version-dependent
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 #: the Z-mesh axis name the sharded executors use.
 SPATIAL_AXIS = "z"
 
@@ -74,15 +68,6 @@ class ShardGeometryError(ValueError):
     this to a failed telemetry record (fail_type='shard_geometry') instead
     of letting it escape — unlike other ValueErrors, which indicate bugs
     or bad input and propagate."""
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static size of a shard_map axis (compat across jax versions)."""
-    try:
-        return jax.lax.axis_size(axis_name)  # jax >= 0.4.32-ish
-    except AttributeError:
-        size = jax.core.axis_frame(axis_name)  # 0.4.37: returns the int
-        return getattr(size, "size", size)
 
 
 @functools.lru_cache(maxsize=32)
@@ -165,7 +150,7 @@ def halo_exchange_z(x: jax.Array, halo: int, axis_name: str) -> jax.Array:
     """
     if halo == 0:
         return x
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         pad = [(0, 0), (halo, halo), (0, 0), (0, 0), (0, 0)]
         return jnp.pad(x, pad)
@@ -304,7 +289,7 @@ def _slab_megakernel(
     For int8w the exchange ships the *quantized* slab (int8 halos — the
     cheapest collectives of the family) and the kernel dequants in VMEM.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     dloc = x.shape[1]
     radius = sum(cfg.dilations)
     if precision == "int8w" and x.dtype != jnp.int8:
@@ -392,14 +377,14 @@ def sharded_executor_apply(
         # quantized weights (and the prep is not replicated per device)
         params = quantize.prepare_params(params, cfg, precision)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda p, xs: slab_fn(p, xs, cfg, axis, precision),
         mesh=mesh,
         in_specs=(P(), in_spec),
         out_specs=in_spec,
-        # pallas_call has no replication rule; all our outputs are honestly
-        # P(None, "z", ...)-sharded, so skipping the rep check is sound.
-        check_rep=False,
+        # pallas_call has no varying-manual-axes rule; all our outputs are
+        # honestly P(None, "z", ...)-sharded, so skipping the check is sound.
+        check_vma=False,
     )
     # Lay inputs out to match the specs (callers may pass single-device arrays).
     params = jax.device_put(params, NamedSharding(mesh, P()))
@@ -433,7 +418,7 @@ def sharded_apply(
         head = params["head"]
         return meshnet.dilated_conv3d(xs, head["w"], head["b"], dilation=1)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         slab_fn,
         mesh=mesh,
         in_specs=(P(), in_spec),

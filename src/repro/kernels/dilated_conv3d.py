@@ -1,166 +1,48 @@
-"""Pallas TPU kernel for MeshNet's hot-spot: the 3^3 *dilated* 3-D conv.
+"""Pallas TPU kernel for MeshNet's hot spot: one 3^3 *dilated* 3-D conv.
 
 Why a custom kernel (hardware adaptation, DESIGN.md §2)
 -------------------------------------------------------
 Brainchop's WebGL backend runs this conv as fragment-shader passes over 2-D
 texture tilings of the volume; the cost model there is texture bandwidth.
 On TPU the equivalent wall is HBM->VMEM traffic: a 256^3 x 5ch f32 volume is
-335 MB, read 27x by a naive gather-per-tap schedule. This kernel tiles the
-volume into VMEM-resident cubes and reads, per output block, exactly the
-haloed input neighbourhood it needs — a single (block+2*dilation)^3 DMA.
+335 MB, read 27x by a naive gather-per-tap schedule. The kernel tiles the
+volume into VMEM-resident (Z, Y) tiles of whole rows and reads, per output
+tile, exactly the haloed neighbourhood it needs — a single DMA.
 
-Two schedules, selected by ``variant``:
+One conv is a one-layer segment of the depth-first megakernel
+(kernels/megakernel.py): the same plane layout (channels on the sublanes,
+each Z plane flattened row-major onto the lanes), the same haloed DMA, the
+same 27-tap stacked matmul and the same fused affine+ReLU epilogue, which
+folds inference-mode BatchNorm and activation into the conv's output tile
+while it is still in VMEM. ``pallas_fused`` runs the whole MeshNet stack
+as one such segment per layer.
 
-  ``halo`` (default) — the input stays in HBM (``memory_space=ANY``) and the
-    kernel DMAs one haloed window per output block into a VMEM scratch
-    buffer. Per-block traffic is ``(block+2d)^3`` — the read floor for a
-    blocked dilated conv. This replaced the original 27-view schedule,
-    whose traffic was a full ``27*block^3`` per output block regardless of
-    dilation (~28x the floor at d=1, see DESIGN.md §2 traffic table).
-  ``views`` — the original schedule: the +-dilation neighbourhood expressed
-    as 27 disjoint offset views of the same padded input (the canonical
-    BlockSpec halo pattern). Kept as a bit-exactness oracle for the halo
-    schedule (tests/test_kernels.py) and as the reference point for the
-    traffic model in telemetry/traffic.py.
-
-TPU-native design notes
-  * channels-last layout: C rides the lane dimension. MeshNet's C=5 is far
-    below the 128-lane MXU contraction, so the einsum per tap is a VPU
-    (8x128 vreg) FMA, not an MXU matmul — a C<=8 model is *memory-bound* on
-    TPU and the win comes from the blocking, not systolic compute. The
-    kernel is still correct (and becomes MXU-bound) for wide variants
-    (failsafe 21ch / atlas 18ch) where Cin x Cout taps start to matter.
-  * block size: ``block`` (default 16 = max MeshNet dilation) keeps the
-    haloed window at most (3*block)^3 * C * 4 B of VMEM — 2.2 MB at C=5
-    f32, comfortably under the ~16 MB VMEM budget. ``vmem_bytes`` prices
-    the working set exactly and ``dilated_conv3d`` refuses (with a
-    suggested smaller block) before a call would exceed ``VMEM_BUDGET``.
-  * optional fused affine+ReLU epilogue: folds inference-mode BatchNorm and
-    activation into the conv's output block while it is still in VMEM
-    (saves one full HBM round-trip per layer — see EXPERIMENTS.md §Perf).
-  * whole-stack fusion: kernels/megakernel.py goes one step further and
-    runs *all* hidden layers per VMEM-resident tile (EXPERIMENTS.md §Perf
-    H9); this module remains the per-layer building block.
+Its tile is the megakernel planner's: ``megakernel.plan`` of the one
+layer at the call's own array dtypes, which prices the working set as
+the buffers occupy it (``vmem_bytes``). An explicit ``block`` that would
+exceed ``VMEM_BUDGET`` is refused before the ``pallas_call``.
 
 Validated in interpret mode on CPU against kernels/ref.py for every
-(shape, dtype, dilation, channels) in the test sweep.
+(shape, dtype, dilation, channels) in the test sweep, and compiled for a
+v5e chip by tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-#: VMEM ceiling per core the guard prices against (v4/v5e have 16 MiB).
-VMEM_BUDGET = 16 * 1024 * 1024
+from repro.kernels import megakernel
 
-
-def _halo_kernel(*refs, dilation: int, block: int, fuse_affine: bool):
-    """Haloed-load kernel body. refs = x(ANY) + w + b (+ s, o) + out + scratch."""
-    if fuse_affine:
-        x_ref, w_ref, b_ref, s_ref, o_ref, out_ref, buf, sem = refs
-    else:
-        x_ref, w_ref, b_ref, out_ref, buf, sem = refs
-        s_ref = o_ref = None
-    bi, zi, yi, xi = (pl.program_id(i) for i in range(4))
-    d, b = dilation, block
-    size = b + 2 * d
-    # One DMA per output block: exactly the (b+2d)^3 neighbourhood, from the
-    # d-padded input resident in HBM.
-    dma = pltpu.make_async_copy(
-        x_ref.at[
-            bi,
-            pl.ds(zi * b, size),
-            pl.ds(yi * b, size),
-            pl.ds(xi * b, size),
-            :,
-        ],
-        buf,
-        sem,
-    )
-    dma.start()
-    dma.wait()
-
-    w = w_ref[...]  # (3, 3, 3, Cin, Cout)
-    acc = jnp.zeros((b, b, b, w.shape[-1]), jnp.float32)
-    for tz in (-1, 0, 1):
-        for ty in (-1, 0, 1):
-            for tx in (-1, 0, 1):
-                # Output voxel p reads input p + t*d (correlation, as XLA);
-                # buffer index 0 is global block origin minus d.
-                sl = buf[
-                    d + tz * d : d + tz * d + b,
-                    d + ty * d : d + ty * d + b,
-                    d + tx * d : d + tx * d + b,
-                    :,
-                ]
-                acc = acc + jnp.einsum(
-                    "zyxi,io->zyxo",
-                    sl.astype(jnp.float32),
-                    w[tz + 1, ty + 1, tx + 1].astype(jnp.float32),
-                    preferred_element_type=jnp.float32,
-                )
-    out = acc + b_ref[...].astype(jnp.float32)
-    if fuse_affine:
-        out = out * s_ref[...].astype(jnp.float32) + o_ref[...].astype(jnp.float32)
-        out = jnp.maximum(out, 0.0)
-    out_ref[0] = out.astype(out_ref.dtype)
-
-
-def _views_kernel(*refs, dilation: int, block: int, fuse_affine: bool):
-    """27-view kernel body. refs = 27 input views + w + b (+ scale, offset) + out."""
-    if fuse_affine:
-        *xs, w_ref, b_ref, s_ref, o_ref, out_ref = refs
-    else:
-        *xs, w_ref, b_ref, out_ref = refs
-        s_ref = o_ref = None
-    # Assemble the (3b, 3b, 3b, Cin) neighbourhood from 27 (b,b,b,Cin) views.
-    # Loads stay in VMEM; concatenate is a register/VMEM reshuffle (and is
-    # why this variant's working set is ~2x the halo schedule's —
-    # ``vmem_bytes`` prices the assembled buffer).
-    planes = []
-    for zi in range(3):
-        rows = []
-        for yi in range(3):
-            cols = [xs[zi * 9 + yi * 3 + xi][0] for xi in range(3)]
-            rows.append(jnp.concatenate(cols, axis=2))
-        planes.append(jnp.concatenate(rows, axis=1))
-    nb = jnp.concatenate(planes, axis=0)  # (3b, 3b, 3b, Cin)
-
-    w = w_ref[...]  # (3, 3, 3, Cin, Cout)
-    acc = jnp.zeros((block, block, block, w.shape[-1]), jnp.float32)
-    d = dilation
-    b = block
-    for tz in (-1, 0, 1):
-        for ty in (-1, 0, 1):
-            for tx in (-1, 0, 1):
-                # Output voxel p reads input p + t*d (correlation, as XLA).
-                sl = nb[
-                    b + tz * d : 2 * b + tz * d,
-                    b + ty * d : 2 * b + ty * d,
-                    b + tx * d : 2 * b + tx * d,
-                    :,
-                ]
-                acc = acc + jnp.einsum(
-                    "zyxi,io->zyxo",
-                    sl.astype(jnp.float32),
-                    w[tz + 1, ty + 1, tx + 1].astype(jnp.float32),
-                    preferred_element_type=jnp.float32,
-                )
-    out = acc + b_ref[...].astype(jnp.float32)
-    if fuse_affine:
-        out = out * s_ref[...].astype(jnp.float32) + o_ref[...].astype(jnp.float32)
-        out = jnp.maximum(out, 0.0)
-    out_ref[0] = out.astype(out_ref.dtype)
+#: The planner's VMEM budget (kernels/megakernel.py).
+VMEM_BUDGET = megakernel.VMEM_BUDGET
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("dilation", "block", "interpret", "fuse_affine", "variant"),
+    jax.jit, static_argnames=("dilation", "block", "interpret", "fuse_affine")
 )
 def dilated_conv3d(
     x: jax.Array,
@@ -170,96 +52,50 @@ def dilated_conv3d(
     dilation: int = 1,
     scale: jax.Array | None = None,
     offset: jax.Array | None = None,
-    block: int = 16,
-    interpret: bool = True,
+    block: int | None = None,
+    interpret: bool = False,
     fuse_affine: bool = False,
-    variant: str = "halo",
 ) -> jax.Array:
     """'Same'-padded 3-D dilated conv via Pallas.
 
     x: (B, D, H, W, Cin); w: (3, 3, 3, Cin, Cout); b: (Cout,).
     If ``fuse_affine``: returns relu(conv(x) * scale + offset) — the folded
-    inference BatchNorm epilogue. Requires ``dilation <= block`` and spatial
-    dims divisible by ``block`` (the ops wrapper pads as needed).
-    ``variant`` picks the schedule: "halo" (single haloed DMA per block,
-    the production path) or "views" (27 offset BlockSpec views, the
-    bit-exact legacy oracle).
+    inference BatchNorm epilogue. ``block`` forces a (block, block)
+    (Z, Y) output tile (default: the planner's tile); any spatial shape
+    works (the tile grid is padded). ``interpret`` runs the Pallas
+    interpreter, the CPU correctness path.
     """
-    if dilation > block:
-        raise ValueError(f"dilation {dilation} > block {block}")
-    if variant not in ("halo", "views"):
-        raise ValueError(f"variant must be 'halo' or 'views', got {variant!r}")
     B, D, H, W, Cin = x.shape
     Cout = w.shape[-1]
-    assert D % block == H % block == W % block == 0, (x.shape, block)
-    check_vmem(block, Cin, Cout, dilation=dilation,
-               dtype_bytes=x.dtype.itemsize, variant=variant,
-               weight_bytes=w.dtype.itemsize)
-
-    grid = (B, D // block, H // block, W // block)
-
+    widths = (x.dtype.itemsize, w.dtype.itemsize) + (x.dtype.itemsize,) * 2
+    pln = megakernel.plan((dilation,), Cin, Cout, 0, (D, H, W), widths=widths)
+    if block is not None:
+        tile = pln.segments[0].tile
+        pln = dataclasses.replace(pln, segments=(
+            dataclasses.replace(pln.segments[0], tile=(block, block)),))
+        need = megakernel._segment_vmem_bytes(pln.segments[0], widths=widths, width=W)
+        if need > VMEM_BUDGET:
+            raise ValueError(
+                f"dilated_conv3d block={block} cin={Cin} cout={Cout} "
+                f"dilation={dilation} needs {need / 2**20:.1f} MiB of VMEM, "
+                f"over the {VMEM_BUDGET / 2**20:.0f} MiB budget — the "
+                f"planner's tile is {tile}"
+            )
+    seg = pln.segments[0]
     if fuse_affine:
-        if scale is None:
-            scale = jnp.ones((Cout,), x.dtype)
-        if offset is None:
-            offset = jnp.zeros((Cout,), x.dtype)
-
-    out_spec = pl.BlockSpec(
-        (1, block, block, block, Cout), lambda bi, zi, yi, xi: (bi, zi, yi, xi, 0)
-    )
-    out_shape = jax.ShapeDtypeStruct((B, D, H, W, Cout), x.dtype)
-
-    if variant == "halo":
-        # d of zero padding per side supplies the halo; the padded volume
-        # stays in HBM and each block DMAs its (b+2d)^3 window once.
-        xp = jnp.pad(x, [(0, 0)] + [(dilation, dilation)] * 3 + [(0, 0)])
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]
-        args = [xp]
-        size = block + 2 * dilation
-        scratch = [
-            pltpu.VMEM((size, size, size, Cin), x.dtype),
-            pltpu.SemaphoreType.DMA,
-        ]
-        kernel = functools.partial(
-            _halo_kernel, dilation=dilation, block=block, fuse_affine=fuse_affine
-        )
+        scale = jnp.ones((Cout,), jnp.float32) if scale is None else scale
+        offset = jnp.zeros((Cout,), jnp.float32) if offset is None else offset
     else:
-        # One extra block of zero padding per side supplies the halo.
-        xp = jnp.pad(x, [(0, 0)] + [(block, block)] * 3 + [(0, 0)])
-        blk = (1, block, block, block, Cin)
-
-        def mk_index(dz, dy, dx):
-            return lambda bi, zi, yi, xi: (bi, zi + dz, yi + dy, xi + dx, 0)
-
-        in_specs = [
-            pl.BlockSpec(blk, mk_index(dz, dy, dx))
-            for dz in range(3)
-            for dy in range(3)
-            for dx in range(3)
-        ]
-        args = [xp] * 27
-        scratch = []
-        kernel = functools.partial(
-            _views_kernel, dilation=dilation, block=block, fuse_affine=fuse_affine
-        )
-
-    in_specs.append(pl.BlockSpec(w.shape, lambda *_: (0,) * 5))  # weights
-    in_specs.append(pl.BlockSpec(b.shape, lambda *_: (0,)))  # bias
-    args += [w, b]
-    if fuse_affine:
-        in_specs.append(pl.BlockSpec(scale.shape, lambda *_: (0,)))
-        in_specs.append(pl.BlockSpec(offset.shape, lambda *_: (0,)))
-        args += [scale, offset]
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*args)
+        scale = offset = None
+    pz, py = pln.padded(seg)
+    act = megakernel.to_planes(
+        x, (dilation, dilation + pz - D), (dilation, dilation + py - H)
+    )
+    out = megakernel.run_segment(
+        act, seg, pln, 0, [(w, b, scale, offset)],
+        interpret=interpret, relu=fuse_affine,
+    )
+    return megakernel.from_planes(out, (D, H, W, Cout))
 
 
 def vmem_bytes(
@@ -268,72 +104,15 @@ def vmem_bytes(
     cout: int,
     dilation: int = 16,
     dtype_bytes: int = 4,
-    variant: str = "halo",
     weight_bytes: int | None = None,
+    width: int = 256,
 ) -> int:
-    """Exact VMEM working set of one grid step, bytes.
-
-    ``halo``: the (block+2d)^3 DMA'd window + f32 accumulator + output
-    block + weights. ``views``: the 27 streamed views *plus* the assembled
-    (3*block)^3 neighbourhood buffer the original estimate omitted (it
-    undercounted the working set ~2x), + accumulator + output + weights.
-    ``dtype_bytes``/``weight_bytes`` come from the actual array dtypes
-    (the precision policy, kernels/quantize.py): bf16 activations halve
-    the window, int8 weights quarter the tap block — ``weight_bytes``
-    defaults to ``dtype_bytes`` for the uniform legacy case.
-    """
-    acc = block**3 * cout * 4  # f32 accumulator
-    out = block**3 * cout * dtype_bytes
-    wgt = 27 * cin * cout * (weight_bytes or dtype_bytes)
-    if variant == "halo":
-        inp = (block + 2 * dilation) ** 3 * cin * dtype_bytes
-    else:
-        views = 27 * block**3 * cin * dtype_bytes
-        assembled = (3 * block) ** 3 * cin * dtype_bytes
-        inp = views + assembled
-    return inp + acc + out + wgt
-
-
-def suggest_block(
-    cin: int,
-    cout: int,
-    dilation: int,
-    dtype_bytes: int = 4,
-    variant: str = "halo",
-    budget: int = VMEM_BUDGET,
-    weight_bytes: int | None = None,
-) -> int | None:
-    """Largest block (multiple of 8, >= dilation) whose working set fits."""
-    for cand in (64, 56, 48, 40, 32, 24, 16, 8):
-        if cand < dilation:
-            break
-        if vmem_bytes(cand, cin, cout, dilation, dtype_bytes, variant,
-                      weight_bytes) <= budget:
-            return cand
-    return None
-
-
-def check_vmem(
-    block: int,
-    cin: int,
-    cout: int,
-    dilation: int,
-    dtype_bytes: int = 4,
-    variant: str = "halo",
-    budget: int = VMEM_BUDGET,
-    weight_bytes: int | None = None,
-) -> int:
-    """Raise (with a suggested smaller block) before a pallas_call that
-    would exceed the ~16 MB VMEM budget; returns the priced working set."""
-    need = vmem_bytes(block, cin, cout, dilation, dtype_bytes, variant,
-                      weight_bytes)
-    if need > budget:
-        hint = suggest_block(cin, cout, dilation, dtype_bytes, variant,
-                             budget, weight_bytes)
-        fix = f"try block={hint}" if hint else "no block fits; shard channels"
-        raise ValueError(
-            f"dilated_conv3d[{variant}] block={block} cin={cin} cout={cout} "
-            f"dilation={dilation} needs {need / 2**20:.1f} MiB of VMEM, over "
-            f"the {budget / 2**20:.0f} MiB budget — {fix}"
-        )
-    return need
+    """VMEM bytes of one (block, block) grid step on volumes ``width``
+    voxels wide, as megakernel._segment_vmem_bytes prices the one-layer
+    segment; ``weight_bytes`` defaults to ``dtype_bytes``."""
+    return megakernel._segment_vmem_bytes(
+        megakernel.Segment(start=0, dilations=(dilation,), cin=cin,
+                           channels=cout, tile=(block, block)),
+        widths=(dtype_bytes, weight_bytes or dtype_bytes, dtype_bytes, dtype_bytes),
+        width=width,
+    )

@@ -1,47 +1,64 @@
 """Depth-first tiled MeshNet megakernel — the whole stack per VMEM tile.
 
-The per-layer fused kernel (kernels/dilated_conv3d.py) still writes and
-re-reads the full activation volume once per layer, so a 9-layer MeshNet
-forward moves ~10 full volumes of HBM traffic even with a perfect conv.
-This module inverts the loop order: instead of *breadth-first* (each layer
-over the whole volume), it runs *depth-first* — partition the output into
-tiles, and for each tile run **all** hidden layers back-to-back inside a
-single ``pallas_call``, keeping the activation tile in VMEM across layers.
-Each tile loads its haloed input region once (halo inflated by the sum of
-the 3^3 dilations it crosses, so the final tile is exact), and hidden
-activations never touch HBM at all. The 1x1x1 classifier head folds into
-the last call, so a whole forward is: read the haloed input tiles, write
-the logits. See DESIGN.md §2 (depth-first tiling & HBM traffic model) and
-EXPERIMENTS.md §Perf H9.
+A per-layer schedule writes and re-reads the full activation volume once
+per layer, so a 9-layer MeshNet forward moves ~10 full volumes of HBM
+traffic even with a perfect conv. This module inverts the loop order:
+instead of *breadth-first* (each layer over the whole volume), it runs
+*depth-first* — partition the output into tiles, and for each tile run
+**all** hidden layers back-to-back inside a single ``pallas_call``,
+keeping the activation tile in VMEM across layers. Each tile loads its
+haloed input region once (halo inflated by the sum of the 3^3 dilations
+it crosses, so the final tile is exact), and hidden activations never
+touch HBM at all. The 1x1x1 classifier head folds into the last call, so
+a whole forward is: read the haloed input tiles, write the logits. See
+DESIGN.md §2 (depth-first tiling & HBM traffic model) and EXPERIMENTS.md
+§Perf H9.
+
+Plane layout (what the TPU compiler accepts)
+--------------------------------------------
+A TPU vector register is (8 sublanes x 128 lanes), and Mosaic only DMAs
+windows whose two minor dims are whole (8, 128) tiles. A channels-last
+volume puts C = 1, 5 or 21 on the lanes, so every haloed window is
+refused. Every array the kernel touches is therefore in the *plane
+layout* ``(B, Z, Cp, Y * Xp)``:
+
+  * the channels ride the sublanes, zero-padded to ``Cp = ceil(C, 8)``;
+  * a Z plane is flattened row-major onto the lanes, each row padded to
+    ``Xp = ceil(X, 128)`` lanes, so a Y offset is a whole number of lane
+    tiles and the rows of a window are one aligned lane slice;
+  * Z is the leading dim, which a DMA may slice anywhere.
+
+Tiles split Z and Y (with halos); X is never split, so a tile spans whole
+rows and the X boundary is the volume's own edge. One 3^3 tap of the
+conv is then a leading-dim index (Z), an aligned lane offset of ``ty*d``
+rows (Y) and a lane rotation by ``tx*d`` with an edge mask (X), and the
+27 taps of a layer stack along the sublanes into ONE
+``(Cout_p, 27*Cin_p) @ (27*Cin_p, rows*Xp)`` matmul per block of rows.
+``to_planes``/``from_planes`` convert at the boundary of a forward.
 
 Exactness (including the volume boundary)
 -----------------------------------------
-A window that zero-pads only at its own edge diverges from the full-volume
-forward near the *volume* boundary, because 'same' convs re-introduce zero
-padding at every layer (the sub-volume accuracy loss characterised in
-core/patching.py). The megakernel does not inherit that loss: after the
-haloed DMA and after every in-tile layer, positions outside the true
-volume are masked back to zero, reproducing per-layer 'same' padding
+After the haloed DMA and after every in-tile layer, positions outside the
+true volume are masked back to zero, reproducing per-layer 'same' padding
 bit-for-bit — the same trick as core/spatial_shard.py's halo exchange,
 applied inside VMEM. This also means the HBM staging buffers between
 segments can carry uninitialised (never-written) halo borders: whatever
-garbage they hold is masked out at the next DMA, so no staging pad copies
-are needed.
+they hold is masked out as the next segment reads it.
 
 Segmentation — the overlap-add fallback
 ---------------------------------------
 The full schedule's halo (sum(1,2,4,8,16,8,4,2,1) = 46 per side) inflates
-a tile's working set past the ~16 MB VMEM budget for realistic channel
-widths, so ``plan`` splits the layer stack into consecutive *segments*,
-each run depth-first with its own (smaller) halo, with one full activation
-round-trip between segments — the cheap end of the overlap-add spectrum:
-one segment per layer degenerates to the per-layer fused path; one segment
-for the whole stack is the pure megakernel. The planner chooses segment
-boundaries and per-axis tile shapes by dynamic programming over the
-modeled HBM traffic, subject to ``_segment_vmem_bytes`` staying under the
-budget (tiles need not be cubes: the d=16 layer fits best as e.g.
-24x64x64). ``MegakernelPlan.hbm_bytes`` is the traffic model the
-benchmarks and telemetry report (telemetry/traffic.py).
+a tile's working set past the VMEM budget, so ``plan`` splits the layer
+stack into consecutive *segments*, each run depth-first with its own
+(smaller) halo, with one full activation round-trip between segments:
+one segment per layer is the per-layer fused path (``pallas_fused``,
+``plan_for_config(per_layer=True)``); one segment for the whole stack is
+the pure megakernel. The planner chooses segment boundaries and (Z, Y)
+tile shapes by dynamic programming over the modeled HBM traffic, subject
+to ``_segment_vmem_bytes`` — every buffer priced at the bytes it really
+occupies in VMEM, padded rows and lanes included — staying under the
+budget. ``MegakernelPlan.hbm_bytes`` is the traffic model the benchmarks
+and telemetry report (telemetry/traffic.py).
 """
 
 from __future__ import annotations
@@ -59,20 +76,40 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import quantize
 
-#: Default planning budget: 16 MiB VMEM per core minus Mosaic headroom.
-VMEM_BUDGET = 14 * 1024 * 1024
+#: TPU vector register geometry: lanes (minor dim) x sublanes.
+LANE = 128
+SUBLANE = 8
 
-#: Per-axis tile-size candidates (sublane-friendly multiples of 8). The
-#: intermediate sizes (40, 56, 80, 160, 192) exist for the reduced-
-#: precision working sets: a bf16/int8 tile often fits at e.g. 40 where
-#: 48 busts the budget and 32 wastes halo — the fp32 plans are unchanged
-#: by the finer grid (tests/test_precision.py pins the paper-volume fp32
-#: bytes).
+#: Planning budget for one kernel's priced buffers. A v5e TensorCore has
+#: 128 MiB of VMEM (the compiler's default scoped limit is only 16 MiB,
+#: so every call passes ``vmem_limit_bytes`` explicitly).
+VMEM_BUDGET = 64 * 1024 * 1024
+
+#: What each call asks the compiler for on top of the planned budget:
+#: room for Mosaic's own temporaries (spills, the rotated tap slices).
+VMEM_HEADROOM = 16 * 1024 * 1024
+
+#: Cap on the f32 im2col operand of one row block (27 stacked tap slices).
+STACK_BYTES = 2 * 1024 * 1024
+
+#: Per-axis (Z, Y) tile-size candidates. Neither axis meets an alignment
+#: rule (Z is a leading dim, a Y row is a whole lane-tile multiple), so
+#: the grid is chosen for the DP's resolution only.
 TILE_CANDIDATES = (8, 16, 24, 32, 40, 48, 56, 64, 80, 96, 128, 160, 192, 256)
 
 
 def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def rows(c: int) -> int:
+    """Sublane rows a C-channel plane occupies (channels padded to 8)."""
+    return _ceil_to(c, SUBLANE)
+
+
+def lanes(x: int) -> int:
+    """Lanes one padded row of an X-wide plane occupies."""
+    return _ceil_to(x, LANE)
 
 
 #: per-role HBM/VMEM byte widths of one plan: (activation/compute,
@@ -105,13 +142,14 @@ def plan_widths(
 
 @dataclasses.dataclass(frozen=True)
 class Segment:
-    """A consecutive run of hidden layers executed depth-first per tile."""
+    """A consecutive run of hidden layers executed depth-first per tile.
+    ``tile`` is the (Z, Y) output tile; X is never split."""
 
     start: int  # index of the first layer in cfg.dilations
     dilations: tuple[int, ...]
     cin: int  # input channels (in_channels for the first segment)
     channels: int  # hidden width C
-    tile: tuple[int, int, int]
+    tile: tuple[int, int]
     fuse_head: bool = False  # apply the 1x1x1 head after the last layer
     num_classes: int = 0
 
@@ -123,9 +161,9 @@ class Segment:
     def cout(self) -> int:
         return self.num_classes if self.fuse_head else self.channels
 
-    def buffer_sizes(self) -> list[tuple[int, int, int]]:
-        """Per-layer valid-region sizes: S_0 = tile + 2*halo shrinking by
-        2*d per layer down to S_k = tile exactly."""
+    def buffer_sizes(self) -> list[tuple[int, int]]:
+        """Per-layer valid (Z, Y) extents: S_0 = tile + 2*halo shrinking
+        by 2*d per layer down to S_k = tile exactly."""
         sizes = [tuple(t + 2 * self.halo for t in self.tile)]
         for d in self.dilations:
             sizes.append(tuple(s - 2 * d for s in sizes[-1]))
@@ -133,80 +171,104 @@ class Segment:
         return sizes
 
 
+def _row_block(s_y: int, cin: int, xp: int) -> int:
+    """Rows of one loop iteration: as many as keep the stacked f32 im2col
+    operand (27 * Cin_p x rows * Xp) under ``STACK_BYTES``."""
+    per_row = 27 * rows(cin) * xp * 4
+    return max(1, min(s_y, STACK_BYTES // per_row))
+
+
 def _segment_vmem_bytes(
-    seg: Segment, dtype_bytes: int = 4, widths: Optional[Widths] = None
+    seg: Segment,
+    dtype_bytes: int = 4,
+    widths: Optional[Widths] = None,
+    width: int = 256,
 ) -> int:
-    """VMEM working set of one grid step: the statically allocated scratch
-    (DMA'd input buffer + ping/pong activation buffers + logits staging
-    when the head is fused + weights) **plus** the transient f32
-    accumulator of the widest layer — scratch lives for the whole kernel,
-    and the tap loop's ``acc`` is live alongside it, so omitting it would
-    admit plans that exceed real VMEM (the tap reads themselves stream
-    from the resident buffers and need no second copy). With per-role
-    ``widths`` the DMA'd buffer is priced at the input/staging width, the
-    compute buffers at the activation width, and int8-staging segments
-    additionally hold the quantized output tile they DMA out.
+    """VMEM bytes one grid step of ``seg`` really occupies, for volumes
+    ``width`` voxels wide in X — every buffer at its padded plane-layout
+    size (``rows(C)`` sublanes by ``lanes(width)`` per row):
+
+      * the DMA'd input window (at the input or staging width);
+      * the ping/pong activation buffers of the in-tile layers, the fused
+        head's logits tile, and for int8 staging the quantized output
+        tile (at their storage widths);
+      * the weight, bias and epilogue blocks, double-buffered by the
+        pipeline, and the two per-lane coordinate rows;
+      * the widest layer's per-iteration temporaries: the stacked f32
+        im2col operand, its MXU-width copy, and the f32 accumulator.
     """
     act, wt, inp, stg = widths or (dtype_bytes,) * 4
+    xp = lanes(width)
     ib = inp if seg.start == 0 else stg
+    k = len(seg.dilations)
     sizes = seg.buffer_sizes()
-    buf_in = math.prod(sizes[0]) * seg.cin * ib
-    ping = max(math.prod(s) for s in sizes[1::2]) * seg.channels * act
-    pong = (
-        max(math.prod(s) for s in sizes[2::2]) * seg.channels * act
-        if len(sizes) > 2
-        else 0
-    )
-    wgt = 27 * seg.cin * seg.channels * wt
-    wgt += 27 * seg.channels**2 * wt * (len(seg.dilations) - 1)
-    logits = (
-        math.prod(seg.tile) * seg.num_classes * act if seg.fuse_head else 0
-    )
-    qout = (
-        math.prod(seg.tile) * seg.channels * stg
-        if (not seg.fuse_head and stg < act)
-        else 0
-    )
-    acc = max(math.prod(s) for s in sizes[1:]) * seg.channels * 4  # f32
+    ci, c = rows(seg.cin), rows(seg.channels)
+    quant_out = not seg.fuse_head and stg < act
+
+    def plane(s):
+        return s[0] * s[1] * xp
+
+    total = plane(sizes[0]) * ci * ib
+    # layer li writes sizes[li + 1]: ping for even li, pong for odd li;
+    # the last layer writes the logits / quantized tile instead
+    writes = range(k - 1) if (seg.fuse_head or quant_out) else range(k)
+    for parity in (0, 1):
+        outs = [plane(sizes[li + 1]) for li in writes if li % 2 == parity]
+        total += max(outs, default=0) * c * act
     if seg.fuse_head:
-        acc = max(acc, math.prod(seg.tile) * seg.num_classes * 4)
-    return buf_in + ping + pong + wgt + logits + qout + acc
+        total += plane(seg.tile) * rows(seg.num_classes) * act
+    if quant_out:
+        total += plane(seg.tile) * c * stg
+    wgt = 27 * ci * c * wt + 27 * c * c * wt * (k - 1)
+    wgt += k * 4 * c * LANE * 4  # (C, 1) f32 epilogue columns, lane-padded
+    if seg.fuse_head:
+        wgt += rows(seg.num_classes) * _ceil_to(c, LANE) * wt
+    total += 2 * wgt
+    lmax = max(_row_block(s[1], seg.cin if li == 0 else seg.channels, xp)
+               for li, s in enumerate(sizes[1:])) * xp
+    total += 2 * 2 * SUBLANE * lmax * 4  # lane coordinate rows
+    temps = 0
+    for li, s in enumerate(sizes[1:]):
+        cin_l = ci if li == 0 else c
+        L = _row_block(s[1], seg.cin if li == 0 else seg.channels, xp) * xp
+        temps = max(temps, 27 * cin_l * L * (4 + act) + 2 * c * L * 4)
+    return total + temps
 
 
 def _segment_hbm_bytes(
     seg: Segment,
-    padded: tuple[int, int, int],
+    padded: tuple[int, int],
+    width: int,
     dtype_bytes: int,
     widths: Optional[Widths] = None,
     batch: int = 1,
 ) -> int:
-    """Modeled HBM bytes of one segment: haloed tile reads, per-spatial-
-    tile weight streams, and the central-region write. The ONE formula
-    shared by ``MegakernelPlan.hbm_bytes`` (what telemetry/benchmarks
-    report) and the planner's DP objective — so the plan the DP picks is
-    the minimum of the model it reports. ``widths`` prices each tensor
-    role at its policy byte width: window reads at the input width for the
-    first segment and the staging width after, weight streams at the
-    weight width, the write at the staging width (activation width for the
-    fused-head logits).
+    """Modeled HBM bytes of one segment: haloed window reads (whole
+    padded rows of ``rows(cin)`` planes), per-spatial-tile weight
+    streams, and the central-region write. The ONE formula shared by
+    ``MegakernelPlan.hbm_bytes`` (what telemetry/benchmarks report) and
+    the planner's DP objective — so the plan the DP picks is the minimum
+    of the model it reports (int8 staging aside: the DP prices staging
+    at the compute width, ``plan``). ``widths`` prices each tensor role at its
+    policy byte width.
 
-    ``batch`` scales only the data terms (every batch element's windows
-    are read and its central region written), NOT the weight stream: the
+    ``batch`` scales only the data terms, NOT the weight stream: the
     launch grid iterates batch innermost, so each segment's weight blocks
     stay resident across the whole batch loop and are re-fetched only
     when the spatial tile advances — one weight stream per launch,
     amortized over all N members."""
     act, wt, inp, stg = widths or (dtype_bytes,) * 4
+    xp = lanes(width)
     ib = inp if seg.start == 0 else stg
     ob = act if seg.fuse_head else stg
+    ci, c = rows(seg.cin), rows(seg.channels)
     ntiles = math.prod(pp // t for pp, t in zip(padded, seg.tile))
-    window = math.prod(t + 2 * seg.halo for t in seg.tile)
-    wgt = 27 * seg.cin * seg.channels * wt
-    wgt += 27 * seg.channels**2 * wt * (len(seg.dilations) - 1)
+    window = math.prod(t + 2 * seg.halo for t in seg.tile) * xp
+    wgt = 27 * ci * c * wt + 27 * c * c * wt * (len(seg.dilations) - 1)
     if seg.fuse_head:
-        wgt += seg.channels * seg.num_classes * wt
-    data = ntiles * window * seg.cin * ib
-    data += math.prod(padded) * seg.cout * ob
+        wgt += c * rows(seg.num_classes) * wt
+    data = ntiles * window * ci * ib
+    data += math.prod(padded) * xp * rows(seg.cout) * ob
     return batch * data + ntiles * wgt
 
 
@@ -224,15 +286,19 @@ class MegakernelPlan:
     vmem_budget: int
     widths: Optional[Widths] = None
 
-    def padded(self, seg: Segment) -> tuple[int, int, int]:
-        """Tile-multiple dims of the region this segment computes."""
-        return tuple(_ceil_to(v, t) for v, t in zip(self.vol, seg.tile))
+    @property
+    def xp(self) -> int:
+        return lanes(self.vol[2])
 
-    def out_dims(self, i: int) -> tuple[int, int, int]:
-        """Spatial dims of segment i's HBM output array. Sized for the
+    def padded(self, seg: Segment) -> tuple[int, int]:
+        """Tile-multiple (Z, Y) dims of the region this segment computes."""
+        return tuple(_ceil_to(v, t) for v, t in zip(self.vol[:2], seg.tile))
+
+    def out_dims(self, i: int) -> tuple[int, int]:
+        """(Z, Y) dims of segment i's HBM output array. Sized for the
         *next* segment's haloed DMA windows: max of both segments' padded
         extents plus the next halo per side (the halo border is never
-        written — its garbage is masked out after the next DMA)."""
+        written — it is masked out as the next segment reads it)."""
         cur = self.padded(self.segments[i])
         if i + 1 == len(self.segments):
             return cur
@@ -241,31 +307,29 @@ class MegakernelPlan:
         return tuple(max(c, p) + 2 * nxt.halo for c, p in zip(cur, pad))
 
     def hbm_bytes(self, batch: int = 1, dtype_bytes: int = 4) -> int:
-        """Modeled HBM traffic of one batched forward: the input pad
-        round-trip, then per segment the haloed tile reads, the weight
-        streams, and the central-region writes (staging halo borders are
-        allocated but never written, so they cost nothing). Data terms
-        scale with ``batch``; the per-segment weight stream is charged
-        once per launch — batch iterates innermost in the kernel grid, so
-        weights DMA'd for a spatial tile serve every batch element
-        (subadditive: ``hbm_bytes(N) < N * hbm_bytes(1)`` whenever the
-        weight term is nonzero; ``batch=1`` is byte-identical to the
-        pre-batching model). A plan optimized for a precision policy
-        prices with its own per-role widths (``dtype_bytes`` is the
-        legacy uniform knob and is ignored when ``widths`` is set)."""
+        """Modeled HBM traffic of one batched forward: the conversion of
+        the input into the padded plane layout (read + padded write), then
+        per segment the haloed tile reads, the weight streams and the
+        central-region writes (staging halo borders are allocated but
+        never written, so they cost nothing), then the conversion of the
+        logits back out of the plane layout. Data terms scale with
+        ``batch``; the per-segment weight stream is charged once per
+        launch. A plan optimized for a precision policy prices with its
+        own per-role widths (``dtype_bytes`` is the legacy uniform knob
+        and is ignored when ``widths`` is set)."""
         widths = self.widths or (dtype_bytes,) * 4
-        inp = widths[2]
-        total = 0
-        first = self.segments[0]
+        act, inp = widths[0], widths[2]
+        xp = self.xp
+        first, last = self.segments[0], self.segments[-1]
         p0 = self.padded(first)
-        # host-side zero-pad of the input volume (read + padded write, at
-        # the policy's input storage width) — per batch element
-        total += batch * math.prod(self.vol) * first.cin * inp
-        total += batch * math.prod(t + 2 * first.halo for t in p0) * first.cin * inp
+        total = batch * math.prod(self.vol) * first.cin * inp
+        total += batch * math.prod(t + 2 * first.halo for t in p0) * xp * rows(first.cin) * inp
         for seg in self.segments:
             total += _segment_hbm_bytes(
-                seg, self.padded(seg), dtype_bytes, widths, batch=batch
+                seg, self.padded(seg), self.vol[2], dtype_bytes, widths, batch=batch
             )
+        total += batch * math.prod(self.padded(last)) * xp * rows(last.cout) * act
+        total += batch * math.prod(self.vol) * last.cout * act
         return total
 
 
@@ -281,30 +345,37 @@ def plan(
     precision: Optional[str] = None,
     int8_staging: Optional[bool] = None,
     batch: int = 1,
+    per_layer: bool = False,
+    widths: Optional[Widths] = None,
 ) -> MegakernelPlan:
-    """Choose segment boundaries and per-axis tiles by DP over modeled
-    HBM traffic, subject to each segment's working set fitting VMEM.
+    """Choose segment boundaries and (Z, Y) tiles by DP over modeled HBM
+    traffic, subject to each segment's working set fitting VMEM.
 
     ``precision`` prices every tensor role at its policy width
-    (``plan_widths``), which is where the second-order traffic win comes
-    from: a bf16/int8 working set is 2-4x smaller, so the DP affords
-    larger tiles and fewer halo re-fetches on top of the per-byte cut.
-    ``precision=None`` keeps the legacy uniform-``dtype_bytes`` model
-    (byte-identical fp32 plans).
+    (``plan_widths``): a bf16/int8 working set is smaller, so the DP
+    affords larger tiles and fewer halo re-fetches on top of the
+    per-byte cut. ``precision=None`` keeps the legacy uniform-
+    ``dtype_bytes`` model. Under int8 staging the DP still prices the
+    staging streams at the compute width: every int8 boundary is a lossy
+    requantization, so the plan keeps the boundaries of the lossless
+    schedule and only narrows them (the plan reports its true bytes).
 
     ``batch`` co-optimizes the tile shape against the batch size: the DP
     objective scales the data terms by N while charging the weight stream
-    once per launch, so at larger batches the planner leans toward the
-    tile that minimizes halo re-reads rather than weight re-streams. The
-    VMEM constraint is unchanged — the grid iterates one (batch element,
-    tile) at a time, so the working set never grows with batch and a plan
-    feasible at batch 1 stays feasible at any batch.
+    once per launch. The VMEM constraint is unchanged — the grid iterates
+    one (tile, batch element) at a time, so a plan feasible at batch 1
+    stays feasible at any batch.
+
+    ``per_layer`` restricts every segment to one layer: the per-layer
+    fused schedule (``pallas_fused``) is this plan. ``num_classes=0``
+    plans a stack without the 1x1x1 head, and ``widths`` gives the
+    per-role byte widths outright: one conv (kernels/dilated_conv3d.py)
+    is the one-layer plan of its own array dtypes.
 
     Raises with an actionable message when even a single layer at the
     smallest tile exceeds the budget (channel width is the only lever
     left at that point). Memoized: the serving path replans the same
-    (model, volume, precision) on every request ("auto" resolution,
-    traffic telemetry, the forward itself).
+    (model, volume, precision) on every request.
     """
     return _plan_cached(
         tuple(int(d) for d in dilations),
@@ -313,8 +384,9 @@ def plan(
         int(num_classes),
         tuple(int(v) for v in vol),
         int(vmem_budget),
-        plan_widths(precision, dtype_bytes, int8_staging),
+        widths or plan_widths(precision, dtype_bytes, int8_staging),
         int(batch),
+        bool(per_layer),
     )
 
 
@@ -328,16 +400,20 @@ def _plan_cached(
     vmem_budget: int,
     widths: Widths,
     batch: int = 1,
+    per_layer: bool = False,
 ) -> MegakernelPlan:
     n = len(dils)
-    act, wt, inp, stg = widths
+    act, _, inp, stg = widths
+    # Segment boundaries are priced at the compute width: an int8 staging
+    # boundary requantizes the activations (lossy, unlike a bf16 one), so
+    # int8 may narrow the streams of the boundaries the lossless schedule
+    # takes but never buys an extra one. Feasibility uses the real widths.
+    cost_widths = widths[:3] + (max(stg, act),)
+    xp = lanes(vol[2])
     # Oversize tiles only waste padding: cap candidates near the volume.
     cands = [
-        np.array(
-            [t for t in TILE_CANDIDATES if t <= _ceil_to(v, 8)] or [8],
-            dtype=np.float64,
-        )
-        for v in vol
+        [t for t in TILE_CANDIDATES if t <= _ceil_to(v, 8)] or [8]
+        for v in vol[:2]
     ]
 
     def seg_for(i: int, j: int, tile) -> Segment:
@@ -347,91 +423,54 @@ def _plan_cached(
             cin=in_channels if i == 0 else channels,
             channels=channels,
             tile=tile,
-            fuse_head=(j == n),
+            fuse_head=(j == n and num_classes > 0),
             num_classes=num_classes,
         )
 
-    # Vectorized DP: for each (i, j) the per-axis buffer sizes are affine
-    # in the tile candidate, so every per-tile quantity (VMEM working set,
-    # modeled segment traffic) is evaluated over the whole candidate grid
-    # with numpy broadcasting — the plan is exact, only ~100x faster than
-    # constructing a Segment per (i, j, tile). All intermediates are
-    # integer-valued float64 (< 2**53), so comparisons are exact; the
-    # chosen plan's bytes are re-derived in int arithmetic by hbm_bytes.
+    # DP over segment boundaries; each (i, j) picks its cheapest feasible
+    # tile. Segments are short (<= n layers) and the tile grid is ~14^2,
+    # so pricing each candidate with the exact per-segment formulas keeps
+    # the DP objective and the reported model one formula.
     INF = float("inf")
     best: list[float] = [INF] * (n + 1)
     best[n] = 0.0
-    choice: list[tuple[int, tuple[int, int, int]] | None] = [None] * (n + 1)
-    grids = np.meshgrid(*cands, indexing="ij")  # (A,B,C) per axis
-    vol_np = [float(v) for v in vol]
+    choice: list[tuple[int, tuple[int, int]] | None] = [None] * (n + 1)
     for i in range(n - 1, -1, -1):
-        cin = in_channels if i == 0 else channels
-        ib = inp if i == 0 else stg
-        for j in range(i + 1, n + 1):
-            d_ij = dils[i:j]
-            h = sum(d_ij)
-            k = j - i
-            fuse_head = j == n
-            cout = num_classes if fuse_head else channels
-            ob = act if fuse_head else stg
-            # per-layer valid-region products P_l over the tile grid
-            cum = 0
-            prods = []
-            for l in range(k + 1):
-                s = 2 * (h - cum)
-                prods.append(
-                    (grids[0] + s) * (grids[1] + s) * (grids[2] + s)
-                )
-                if l < k:
-                    cum += d_ij[l]
-            wgt = 27 * cin * channels * wt + 27 * channels**2 * wt * (k - 1)
-            wgt_h = wgt + (channels * num_classes * wt if fuse_head else 0)
-            buf_in = prods[0] * (cin * ib)
-            ping = np.maximum.reduce(prods[1::2]) * (channels * act)
-            pong = (
-                np.maximum.reduce(prods[2::2]) * (channels * act)
-                if k >= 2
-                else 0.0
-            )
-            acc = np.maximum.reduce(prods[1:]) * (channels * 4)
-            tilep = prods[k]  # sizes[-1] == tile exactly
-            logits = tilep * (num_classes * act) if fuse_head else 0.0
-            if fuse_head:
-                acc = np.maximum(acc, tilep * (num_classes * 4))
-            qout = (
-                tilep * (channels * stg)
-                if (not fuse_head and stg < act)
-                else 0.0
-            )
-            vmem = buf_in + ping + pong + wgt + logits + qout + acc
-            padded = [np.ceil(v / g) * g for v, g in zip(vol_np, grids)]
-            ntiles = (
-                (padded[0] / grids[0]) * (padded[1] / grids[1]) * (padded[2] / grids[2])
-            )
-            # data terms × batch, weight stream once per launch — the
-            # same split hbm_bytes reports (batch innermost in the grid)
-            cost = batch * ntiles * (prods[0] * (cin * ib)) + ntiles * wgt_h
-            cost += batch * padded[0] * padded[1] * padded[2] * (cout * ob)
-            if i == 0:
-                cost += batch * math.prod(vol) * (cin * inp)
-                cost += batch * (
-                    (padded[0] + 2 * h) * (padded[1] + 2 * h) * (padded[2] + 2 * h)
-                ) * (cin * inp)
-            cost = np.where(vmem <= vmem_budget, cost, INF)
-            flat = int(np.argmin(cost))
-            c = float(cost.reshape(-1)[flat]) + best[j]
-            if c < best[i]:
-                best[i] = c
-                idx = np.unravel_index(flat, cost.shape)
-                choice[i] = (
-                    j,
-                    tuple(int(cands[ax][idx[ax]]) for ax in range(3)),
-                )
+        for j in range(i + 1, (i + 2 if per_layer else n + 1)):
+            h = sum(dils[i:j])
+            top = None
+            for tz in cands[0]:
+                for ty in cands[1]:
+                    seg = seg_for(i, j, (tz, ty))
+                    if _segment_vmem_bytes(seg, widths=widths, width=vol[2]) > vmem_budget:
+                        continue
+                    padded = (_ceil_to(vol[0], tz), _ceil_to(vol[1], ty))
+                    cost = _segment_hbm_bytes(seg, padded, vol[2], 4, cost_widths, batch)
+                    if i == 0:
+                        cost += batch * (
+                            math.prod(vol) * in_channels * inp
+                            + (padded[0] + 2 * h) * (padded[1] + 2 * h) * xp
+                            * rows(in_channels) * inp
+                        )
+                    if j == n:
+                        cost += batch * (
+                            padded[0] * padded[1] * xp * rows(seg.cout)
+                            + math.prod(vol) * seg.cout
+                        ) * act
+                    if top is None or cost < top[0]:
+                        top = (cost, (tz, ty))
+            if top is not None and top[0] + best[j] < best[i]:
+                best[i] = top[0] + best[j]
+                choice[i] = (j, top[1])
     if best[0] == INF:
-        one = seg_for(0, 1, (8, 8, 8))
-        need = _segment_vmem_bytes(one, widths=widths)
+        need, gi = max(
+            (_segment_vmem_bytes(seg_for(i, i + 1, (8, 8)), widths=widths,
+                                 width=vol[2]), i)
+            for i in range(n)
+        )
         raise ValueError(
-            f"megakernel plan infeasible: one layer at tile (8,8,8) needs "
+            f"megakernel plan infeasible: layer {gi} (dilation {dils[gi]}) "
+            f"alone at tile (8, 8) needs "
             f"{need / 2**20:.1f} MiB of VMEM, "
             f"over the {vmem_budget / 2**20:.0f} MiB budget — reduce channel "
             f"width ({channels}) or raise vmem_budget"
@@ -459,6 +498,7 @@ def plan_for_config(
     precision: Optional[str] = None,
     int8_staging: Optional[bool] = None,
     batch: int = 1,
+    per_layer: bool = False,
 ) -> MegakernelPlan:
     """``plan`` from a MeshNetConfig-shaped object. With a ``precision``,
     int8 staging defaults to whether the config has BatchNorm statistics
@@ -476,7 +516,46 @@ def plan_for_config(
         precision=precision,
         int8_staging=int8_staging,
         batch=batch,
+        per_layer=per_layer,
     )
+
+
+# ------------------------------------------------------------ layout ---
+
+
+def to_planes(x: jax.Array, pad_z, pad_y) -> jax.Array:
+    """(B, D, H, W, C) -> plane layout (B, Z, rows(C), Y * lanes(W)),
+    zero-padding Z and Y by ``pad_z``/``pad_y`` (before, after) pairs."""
+    B, D, H, W, C = x.shape
+    x = jnp.pad(x, [(0, 0), pad_z, pad_y, (0, lanes(W) - W), (0, rows(C) - C)])
+    _, Z, Y, X, Cp = x.shape
+    return x.transpose(0, 1, 4, 2, 3).reshape(B, Z, Cp, Y * X)
+
+
+def from_planes(a: jax.Array, shape: tuple[int, int, int, int]) -> jax.Array:
+    """Plane layout -> (B, D, H, W, C) for true dims ``shape = (D, H, W, C)``."""
+    D, H, W, C = shape
+    B, Z, Cp, L = a.shape
+    xp = lanes(W)
+    a = a.reshape(B, Z, Cp, L // xp, xp).transpose(0, 1, 3, 4, 2)
+    return a[:, :D, :H, :W, :C]
+
+
+def _tap_matrix(w: jax.Array, ci: int, co: int) -> jax.Array:
+    """(3, 3, 3, Cin, Cout) taps -> (co, 27 * ci): column t * ci + i holds
+    tap t = 9 tz + 3 ty + tx of input channel i, the row order in which
+    the kernel stacks its shifted slices."""
+    w = jnp.pad(w, [(0, 0)] * 3 + [(0, ci - w.shape[3]), (0, co - w.shape[4])])
+    return w.transpose(4, 0, 1, 2, 3).reshape(co, 27 * ci)
+
+
+def _column(v: jax.Array, c: int, fill: float = 0.0) -> jax.Array:
+    """(C,) per-channel vector -> (c, 1) f32 column (padded rows ``fill``)."""
+    v = v.astype(jnp.float32)
+    return jnp.pad(v, (0, c - v.shape[0]), constant_values=fill).reshape(c, 1)
+
+
+# ------------------------------------------------------------ kernel ---
 
 
 def _segment_kernel(
@@ -485,6 +564,9 @@ def _segment_kernel(
     vol: tuple[int, int, int],
     out_halo: int,
     use_affine: bool,
+    relu: bool,
+    blocks: tuple[int, ...],
+    mxu_dtype,
     has_z_bounds: bool = False,
     deq_in: bool = False,
     quant_out: bool = False,
@@ -494,71 +576,62 @@ def _segment_kernel(
     layer so per-layer 'same' zero padding is reproduced exactly), then
     DMA the finished tile (or fused-head logits) back out.
 
+    Each layer loops over (output plane, block of ``blocks[li]`` rows);
+    an iteration stacks the 27 shifted (Cin_p, rows * Xp) tap slices
+    along the sublanes and contracts them with the layer's
+    (Cout_p, 27 * Cin_p) tap matrix in one MXU matmul, accumulating in
+    f32. The last row block of a plane is clamped to end at the plane's
+    edge: it recomputes a few rows of its neighbour, which is idempotent.
+
     ``has_z_bounds`` adds a dynamic (2,)-int32 SMEM input narrowing the
     valid Z interval below ``[0, vol[0])`` — the sharded executor
     (core/spatial_shard.py) uses it to place the *true* volume boundary
-    inside a slab+halo window, so pod-edge slabs re-zero their
-    out-of-volume halo per layer exactly like full-volume 'same' padding.
+    inside a slab+halo window.
 
     int8w staging (kernels/quantize.py): ``deq_in`` adds a per-channel
-    fp32 vector that dequantizes the DMA'd int8 staging window on the fly
-    in VMEM (applied per tap slice of the segment's first layer — the
-    only layer that reads the buffer); ``quant_out`` adds the symmetric
-    per-channel scale the segment's last-layer output is quantized with
-    before the output DMA, so what crosses HBM between segments is int8.
-    Per-output-channel int8 *weights* need neither: their dequant scale
-    is already folded into the affine epilogue (quantize.fold_epilogue).
+    column that dequantizes the DMA'd int8 staging window as the first
+    layer reads it; ``quant_out`` adds the per-channel scale the last
+    layer's output is quantized with before the output DMA, so what
+    crosses HBM between segments is int8.
     """
     k = len(seg.dilations)
     per_layer = 4 if use_affine else 2
     n_head = 2 if seg.fuse_head else 0
-    n_extra = int(deq_in) + int(quant_out) + int(has_z_bounds)
-    n_in = 1 + k * per_layer + n_head + n_extra
-    x_ref = refs[0]
-    layer_refs = [
-        refs[1 + i * per_layer : 1 + (i + 1) * per_layer] for i in range(k)
-    ]
-    head_refs = (
-        refs[1 + k * per_layer : 1 + k * per_layer + n_head]
-        if seg.fuse_head
-        else None
-    )
-    pos = 1 + k * per_layer + n_head
+    x_ref, xcol_ref, yrow_ref = refs[0], refs[1], refs[2]
+    pos = 3
+    layer_refs = [refs[pos + i * per_layer: pos + (i + 1) * per_layer] for i in range(k)]
+    pos += k * per_layer
+    head_refs = refs[pos: pos + n_head]
+    pos += n_head
     deq_ref = refs[pos] if deq_in else None
     pos += int(deq_in)
     qscale_ref = refs[pos] if quant_out else None
     pos += int(quant_out)
     zb_ref = refs[pos] if has_z_bounds else None
-    out_ref = refs[n_in]
-    scratch = refs[n_in + 1 :]
-    buf_in, ping = scratch[0], scratch[1]
-    idx = 2
-    pong = scratch[idx] if k >= 2 else None
-    idx += 1 if k >= 2 else 0
-    logits_buf = scratch[idx] if seg.fuse_head else None
-    idx += 1 if seg.fuse_head else 0
-    qout_buf = scratch[idx] if quant_out else None
-    idx += 1 if quant_out else 0
-    sem = scratch[idx]
+    pos += int(has_z_bounds)
+    out_ref = refs[pos]
+    scratch = list(refs[pos + 1:])
+    sem = scratch.pop()
+    buf_in = scratch.pop(0)
+    final = scratch.pop() if (seg.fuse_head or quant_out) else None
+    ping = scratch[0] if scratch else None
+    pong = scratch[1] if len(scratch) > 1 else None
 
     # batch is the INNERMOST grid axis: the weight/bias/affine blocks use
     # constant index maps, so between consecutive batch steps no input
-    # block index changes and the segment's weights stay VMEM-resident —
-    # one weight stream per spatial tile, amortized over the whole batch
-    # (the split _segment_hbm_bytes prices).
-    zi, yi, xi, bi = (pl.program_id(i) for i in range(4))
-    ids = (zi, yi, xi)
-    tile = seg.tile
+    # block index changes and the segment's weights stay VMEM-resident.
+    zi, yi, bi = (pl.program_id(i) for i in range(3))
+    tz_, ty_ = seg.tile
     h = seg.halo
     sizes = seg.buffer_sizes()
+    xp = lanes(vol[2])
 
     dma = pltpu.make_async_copy(
         x_ref.at[
             bi,
-            pl.ds(zi * tile[0], sizes[0][0]),
-            pl.ds(yi * tile[1], sizes[0][1]),
-            pl.ds(xi * tile[2], sizes[0][2]),
+            pl.ds(zi * tz_, sizes[0][0]),
             :,
+            pl.ds(pl.multiple_of(yi * (ty_ * xp), LANE), sizes[0][1] * xp),
         ],
         buf_in,
         sem.at[0],
@@ -566,102 +639,92 @@ def _segment_kernel(
     dma.start()
     dma.wait()
 
-    def mask(v, size, r):
-        """Zero positions whose global coord (tile origin - r + local) lies
-        outside the true volume — per-layer 'same' padding, and the
-        neutraliser for the staging arrays' unwritten halo borders. With
-        ``z_bounds`` the Z-valid interval is the intersection of
-        ``[0, vol[0])`` and the dynamic ``[zb[0], zb[1])``."""
-        ok = None
-        for ax in range(3):
-            i = jax.lax.broadcasted_iota(jnp.int32, size + (1,), ax)
-            lo = r - ids[ax] * tile[ax]
-            m = (i >= lo) & (i < vol[ax] + lo)
-            if ax == 0 and zb_ref is not None:
-                m = m & (i >= zb_ref[0] + lo) & (i < zb_ref[1] + lo)
-            ok = m if ok is None else (ok & m)
-        return jnp.where(ok, v, jnp.zeros((), v.dtype))
+    def valid(z, y0, L, r):
+        """(1, L) mask of the positions of plane ``z``, rows ``y0 ..``
+        (local coords ``r`` before the tile origin) inside the volume."""
+        gz = jnp.full((1, L), zi * tz_ - r + z, jnp.int32)
+        gy = yrow_ref[:, :L] + (yi * ty_ - r + y0)
+        ok = (gz >= 0) & (gz < vol[0]) & (gy >= 0) & (gy < vol[1])
+        ok = ok & (xcol_ref[:, :L] < vol[2])
+        if zb_ref is not None:
+            ok = ok & (gz >= zb_ref[0]) & (gz < zb_ref[1])
+        return ok
 
-    buf_in[...] = mask(buf_in[...], sizes[0], h)
-
-    prev, prev_size = buf_in, sizes[0]
+    prev = buf_in
     cum = 0
     for li, d in enumerate(seg.dilations):
         w_ref, b_ref = layer_refs[li][0], layer_refs[li][1]
-        size = sizes[li + 1]
+        s_out = sizes[li + 1]
+        r = blocks[li]
+        L = r * xp
+        nb = -(-s_out[1] // r)
         cum += d
-        w = w_ref[...]
-        acc = jnp.zeros(size + (w.shape[-1],), jnp.float32)
-        for tz in (-1, 0, 1):
-            for ty in (-1, 0, 1):
-                for tx in (-1, 0, 1):
-                    sl = prev[
-                        d + tz * d : d + tz * d + size[0],
-                        d + ty * d : d + ty * d + size[1],
-                        d + tx * d : d + tx * d + size[2],
-                        :,
-                    ].astype(jnp.float32)
-                    if li == 0 and deq_ref is not None:
-                        # dequant the int8 staging window on the fly: the
-                        # per-channel scale of the previous segment's
-                        # quantized output (only layer 0 reads buf_in).
-                        sl = sl * deq_ref[...]
-                    acc = acc + jnp.einsum(
-                        "zyxi,io->zyxo",
-                        sl,
-                        w[tz + 1, ty + 1, tx + 1].astype(jnp.float32),
-                        preferred_element_type=jnp.float32,
-                    )
-        out = acc + b_ref[...].astype(jnp.float32)
-        if use_affine:
-            s_ref, o_ref = layer_refs[li][2], layer_refs[li][3]
-            out = out * s_ref[...].astype(jnp.float32) + o_ref[...].astype(
-                jnp.float32
-            )
-        out = jnp.maximum(out, 0.0)
-        if li + 1 < k:
-            out = mask(out, size, h - cum)
-            dst = ping if li % 2 == 0 else pong
-            dst[0 : size[0], 0 : size[1], 0 : size[2], :] = out.astype(dst.dtype)
-            prev, prev_size = dst, size
-        elif quant_out:
-            # last layer of an int8-staging segment: quantize the (exactly
-            # tile-sized) output in VMEM so int8 is what crosses HBM.
-            qout_buf[...] = jnp.clip(
-                jnp.round(out / qscale_ref[...]), -127, 127
-            ).astype(jnp.int8)
-            prev, prev_size = qout_buf, size
+        last = li + 1 == k
+        if last and (seg.fuse_head or quant_out):
+            dst = final
         else:
             dst = ping if li % 2 == 0 else pong
-            dst[0 : size[0], 0 : size[1], 0 : size[2], :] = out.astype(dst.dtype)
-            prev, prev_size = dst, size
+        xs = xcol_ref[:, :L]
+        xmask = {dx: (xs + dx >= 0) & (xs + dx < vol[2]) for dx in (-d, d)}
 
-    if seg.fuse_head:
-        hw_ref, hb_ref = head_refs
-        x = prev[0 : tile[0], 0 : tile[1], 0 : tile[2], :]
-        logits = (
-            jnp.einsum(
-                "zyxi,io->zyxo",
-                x.astype(jnp.float32),
-                hw_ref[...].astype(jnp.float32),
+        def body(it, carry, li=li, d=d, prev=prev, dst=dst, r=r, L=L, nb=nb,
+                 s_out=s_out, last=last, xmask=xmask, w_ref=w_ref, b_ref=b_ref,
+                 rr=h - cum):
+            z = it // nb
+            y0 = jnp.minimum((it % nb) * r, s_out[1] - r)
+            pieces = []
+            for tz in range(3):
+                for ty in range(3):
+                    off = pl.multiple_of((y0 + ty * d) * xp, LANE)
+                    s = prev[z + tz * d, :, pl.ds(off, L)].astype(jnp.float32)
+                    if li == 0:
+                        if deq_ref is not None:
+                            s = s * deq_ref[...]
+                        s = jnp.where(valid(z + tz * d, y0 + ty * d, L, h), s, 0.0)
+                    for tx in range(3):
+                        dx = (tx - 1) * d
+                        if dx == 0:
+                            pieces.append(s)
+                        else:
+                            rolled = pltpu.roll(s, (-dx) % L, 1)
+                            pieces.append(jnp.where(xmask[dx], rolled, 0.0))
+            stack = jnp.concatenate(pieces, axis=0).astype(mxu_dtype)
+            out = jnp.dot(
+                w_ref[...].astype(mxu_dtype), stack,
                 preferred_element_type=jnp.float32,
             )
-            + hb_ref[...].astype(jnp.float32)
-        )
-        logits_buf[...] = logits.astype(logits_buf.dtype)
-        src = logits_buf
-    else:
-        src = prev.at[
-            pl.ds(0, tile[0]), pl.ds(0, tile[1]), pl.ds(0, tile[2]), :
-        ]
+            out = out + b_ref[...]
+            if use_affine:
+                s_ref, o_ref = layer_refs[li][2], layer_refs[li][3]
+                out = out * s_ref[...] + o_ref[...]
+            if relu:
+                out = jnp.maximum(out, 0.0)
+            out = jnp.where(valid(z, y0, L, rr), out, 0.0)
+            if last and seg.fuse_head:
+                hw_ref, hb_ref = head_refs
+                out = jnp.dot(
+                    hw_ref[...].astype(mxu_dtype), out.astype(mxu_dtype),
+                    preferred_element_type=jnp.float32,
+                ) + hb_ref[...]
+            elif last and quant_out:
+                out = jnp.clip(jnp.round(out / qscale_ref[...]), -127.0, 127.0)
+                out = out.astype(jnp.int32)
+            dst[z, :, pl.ds(pl.multiple_of(y0 * xp, LANE), L)] = out.astype(dst.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, s_out[0] * nb, body, 0)
+        prev = dst
+
+    src = prev
+    if src.shape[0] != tz_ or src.shape[2] != ty_ * xp:
+        src = src.at[pl.ds(0, tz_), :, pl.ds(0, ty_ * xp)]
     odma = pltpu.make_async_copy(
         src,
         out_ref.at[
             bi,
-            pl.ds(out_halo + zi * tile[0], tile[0]),
-            pl.ds(out_halo + yi * tile[1], tile[1]),
-            pl.ds(out_halo + xi * tile[2], tile[2]),
+            pl.ds(out_halo + zi * tz_, tz_),
             :,
+            pl.ds(pl.multiple_of((out_halo + yi * ty_) * xp, LANE), ty_ * xp),
         ],
         sem.at[1],
     )
@@ -669,120 +732,151 @@ def _segment_kernel(
     odma.wait()
 
 
-def _run_segment(
+def run_segment(
     act: jax.Array,
     seg: Segment,
     pln: MegakernelPlan,
     i: int,
-    params: dict,
-    use_affine: bool,
-    fold_affine,
+    layers: Sequence[tuple],
+    *,
     interpret: bool,
+    head: Optional[tuple] = None,
+    relu: bool = True,
     z_bounds: jax.Array | None = None,
-    layer_epilogue=None,
     compute_dtype=None,
-    staging_scales: Sequence[jax.Array] | None = None,
+    deq: jax.Array | None = None,
+    qscale: jax.Array | None = None,
 ) -> jax.Array:
-    """Run one plan segment. The legacy fp32 path passes ``fold_affine``;
-    the precision paths pass ``layer_epilogue(layer, global_index) ->
-    (bias, scale, offset)`` (quantize.fold_epilogue with the input scale
-    folded into layer 0) plus ``compute_dtype`` (the ping/pong and logits
-    width) and, for int8 staging, the per-layer ``staging_scales`` that
-    pick this segment's boundary dequant/quant vectors."""
+    """Run segment ``i`` of ``pln`` over the plane-layout array ``act``.
+
+    ``layers`` holds one ``(w, bias, scale, offset)`` per layer of the
+    segment (``scale``/``offset`` None for a plain conv), ``head`` the
+    fused head's ``(w (C, classes), b)``. ``compute_dtype`` is the width
+    of the in-VMEM activations, the MXU operands and the output (default
+    ``act``'s dtype); ``deq``/``qscale`` are the int8 staging columns.
+    Returns the segment's plane-layout output, sized by ``pln.out_dims``.
+    """
     B = act.shape[0]
-    padded = pln.padded(seg)
-    out_dims = pln.out_dims(i)
-    out_halo = (
-        pln.segments[i + 1].halo if i + 1 < len(pln.segments) else 0
-    )
+    vol = pln.vol
+    xp = pln.xp
+    k = len(seg.dilations)
     cdt = act.dtype if compute_dtype is None else compute_dtype
-    int8_stage = staging_scales is not None
-    deq_in = int8_stage and seg.start > 0
-    quant_out = int8_stage and not seg.fuse_head
+    mxu = jnp.float32 if cdt == jnp.float32 else jnp.bfloat16
+    quant_out = qscale is not None
+    use_affine = layers[0][2] is not None
+    ci, c = rows(seg.cin), rows(seg.channels)
+    out_halo = pln.segments[i + 1].halo if i + 1 < len(pln.segments) else 0
+    sizes = seg.buffer_sizes()
+    blocks = tuple(
+        _row_block(s[1], seg.cin if li == 0 else seg.channels, xp)
+        for li, s in enumerate(sizes[1:])
+    )
+    lmax = max(blocks) * xp
+    lane = np.arange(lmax, dtype=np.int32)
 
     args = [act]
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
 
     def add_full(a):
         args.append(a)
         in_specs.append(pl.BlockSpec(a.shape, lambda *_, n=a.ndim: (0,) * n))
 
-    for li in range(len(seg.dilations)):
-        layer = params["layers"][seg.start + li]
-        add_full(layer["w"])
-        if layer_epilogue is not None:
-            bias, scale, offset = layer_epilogue(layer, seg.start + li)
-            add_full(bias)
-            add_full(scale)
-            add_full(offset)
-        else:
-            add_full(layer["b"])
-            if use_affine:
-                scale, offset = fold_affine(layer)
-                add_full(scale)
-                add_full(offset)
+    add_full(jnp.asarray(lane % xp)[None])  # x coordinate of each lane
+    add_full(jnp.asarray(lane // xp)[None])  # row offset of each lane
+    for li, (w, bias, scale, offset) in enumerate(layers):
+        add_full(_tap_matrix(w, ci if li == 0 else c, c))
+        add_full(_column(bias, c))
+        if use_affine:
+            add_full(_column(scale, c))
+            add_full(_column(offset, c))
     if seg.fuse_head:
-        add_full(params["head"]["w"][0, 0, 0])  # (C, num_classes)
-        add_full(params["head"]["b"])
-    if deq_in:
-        add_full(staging_scales[seg.start - 1].astype(jnp.float32))
+        hw, hb = head
+        nc = rows(seg.num_classes)
+        add_full(jnp.pad(hw, [(0, c - hw.shape[0]), (0, nc - hw.shape[1])]).T)
+        add_full(_column(hb, nc))
+    if deq is not None:
+        add_full(_column(deq, ci, 1.0))
     if quant_out:
-        last = seg.start + len(seg.dilations) - 1
-        add_full(staging_scales[last].astype(jnp.float32))
+        add_full(_column(qscale, c, 1.0))
     if z_bounds is not None:
         args.append(z_bounds)
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
 
-    sizes = seg.buffer_sizes()
-    scratch = [
-        pltpu.VMEM(sizes[0] + (seg.cin,), act.dtype),
-        pltpu.VMEM(sizes[1] + (seg.channels,), cdt),
-    ]
-    if len(seg.dilations) >= 2:
-        scratch.append(pltpu.VMEM(sizes[2] + (seg.channels,), cdt))
+    def plane(s):
+        return (s[0], s[1] * xp)
+
+    scratch = [pltpu.VMEM((sizes[0][0], ci, sizes[0][1] * xp), act.dtype)]
+    writes = range(k - 1) if (seg.fuse_head or quant_out) else range(k)
+    for parity in (0, 1):
+        outs = [plane(sizes[li + 1]) for li in writes if li % 2 == parity]
+        if outs:
+            z = max(o[0] for o in outs)
+            L = max(o[1] for o in outs)
+            scratch.append(pltpu.VMEM((z, c, L), cdt))
     if seg.fuse_head:
-        scratch.append(pltpu.VMEM(seg.tile + (seg.num_classes,), cdt))
-    if quant_out:
-        scratch.append(pltpu.VMEM(seg.tile + (seg.channels,), jnp.int8))
+        scratch.append(pltpu.VMEM((seg.tile[0], rows(seg.num_classes), seg.tile[1] * xp), cdt))
+    elif quant_out:
+        scratch.append(pltpu.VMEM((seg.tile[0], c, seg.tile[1] * xp), jnp.int8))
     scratch.append(pltpu.SemaphoreType.DMA((2,)))
 
     kernel = functools.partial(
         _segment_kernel,
         seg=seg,
-        vol=pln.vol,
+        vol=vol,
         out_halo=out_halo,
-        use_affine=use_affine or layer_epilogue is not None,
+        use_affine=use_affine,
+        relu=relu,
+        blocks=blocks,
+        mxu_dtype=mxu,
         has_z_bounds=z_bounds is not None,
-        deq_in=deq_in,
+        deq_in=deq is not None,
         quant_out=quant_out,
     )
     out_dtype = jnp.int8 if quant_out else cdt
+    oz, oy = pln.out_dims(i)
+    padded = pln.padded(seg)
     grid = tuple(p // t for p, t in zip(padded, seg.tile)) + (B,)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct((B,) + out_dims + (seg.cout,), out_dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((B, oz, rows(seg.cout), oy * xp), out_dtype),
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=pln.vmem_budget + VMEM_HEADROOM,
+        ),
         interpret=interpret,
     )(*args)
 
 
+@functools.partial(
+    jax.jit,
+    static_argnums=(2,),
+    static_argnames=(
+        "interpret", "pln", "vmem_budget", "fold_affine", "precision",
+        "per_layer",
+    ),
+)
 def meshnet_apply(
     params,
     x: jax.Array,
     cfg,
     *,
+    interpret: bool = False,
     pln: MegakernelPlan | None = None,
     vmem_budget: int = VMEM_BUDGET,
-    interpret: bool = True,
     fold_affine=None,
     z_bounds: jax.Array | None = None,
     precision: str = "fp32",
     staging_scales: Sequence[jax.Array] | None = None,
+    per_layer: bool = False,
 ) -> jax.Array:
     """Depth-first MeshNet forward (== meshnet.apply, eval mode).
+
+    ``interpret`` runs the kernels in the Pallas interpreter — the CPU
+    correctness path; it is never chosen on a TPU (kernels/ops.py).
 
     ``fold_affine`` maps a layer dict to the folded inference-BN
     (scale, offset); ops.meshnet_apply_megakernel supplies it (kept
@@ -794,31 +888,39 @@ def meshnet_apply(
     volume's extent inside a slab+halo window (core/spatial_shard.py).
 
     ``precision`` selects the storage policy (kernels/quantize.py):
-    "fp32" is the legacy bit-exact path below; "bf16" runs the same
-    schedule with bf16 buffers/weights and fp32 accumulate (rounding only
-    at HBM crossings); "int8w" additionally streams per-output-channel
-    int8 weights (dequant folded into the affine epilogue), the int8-
-    quantized conformed input, and — when BatchNorm statistics or the
-    given ``staging_scales`` (quantize.calibrate) bound the activations —
-    int8 inter-segment staging, dequantized on the fly in VMEM. The DP
-    plan is re-optimized for the policy's byte widths, so smaller working
-    sets buy larger tiles and fewer halo re-fetches on top of the per-
-    byte cut (EXPERIMENTS.md H11).
+    "fp32" is the f32 path; "bf16" runs the same schedule with bf16
+    buffers/weights and fp32 accumulate, rounding at every layer output;
+    "int8w" additionally streams per-output-channel int8 weights (dequant
+    folded into the affine epilogue), the int8-quantized conformed input,
+    and — when BatchNorm statistics or the given ``staging_scales``
+    (quantize.calibrate) bound the activations — int8 inter-segment
+    staging, dequantized on the fly in VMEM. A given ``pln`` decides: a
+    plan priced with bf16 staging (``int8_staging=False``) stages bf16.
+
+    ``per_layer`` plans one segment per layer: the ``pallas_fused``
+    schedule, which stages every layer through HBM (at the activation
+    width — it never stages int8).
     """
     if x.ndim == 4:
         x = x[..., None]
     B, D, H, W, Cin = x.shape
     vol = (D, H, W)
-    # branch-specific setup; the pad-and-run-segments tail below is shared
     if precision == "fp32":
         if pln is None:
             pln = plan_for_config(
-                cfg, vol, vmem_budget=vmem_budget, dtype_bytes=x.dtype.itemsize
+                cfg, vol, vmem_budget=vmem_budget, dtype_bytes=x.dtype.itemsize,
+                per_layer=per_layer,
             )
-        use_affine = bool(cfg.use_batchnorm)
-        if use_affine and fold_affine is None:
+        if cfg.use_batchnorm and fold_affine is None:
             raise ValueError("fold_affine is required when cfg.use_batchnorm")
-        layer_epilogue = compute_dtype = staging_scales = None
+
+        def epilogue(layer, gi):
+            if not cfg.use_batchnorm:
+                return layer["b"], None, None
+            return (layer["b"],) + tuple(fold_affine(layer))
+
+        compute_dtype = None
+        staging_scales = None
     else:
         quantize.validate(precision)
         params = quantize.prepare_params(params, cfg, precision)
@@ -826,10 +928,11 @@ def meshnet_apply(
         if precision == "int8w":
             if x.dtype != jnp.int8:
                 x = quantize.quantize_input(x)
-            if staging_scales is None:
+            if staging_scales is None and not per_layer:
                 staging_scales = quantize.staging_scales_from_bn(params, cfg)
         else:
             x = x.astype(compute_dtype)
+        if per_layer:
             staging_scales = None
         if pln is None:
             pln = plan_for_config(
@@ -838,14 +941,13 @@ def meshnet_apply(
                 vmem_budget=vmem_budget,
                 precision=precision,
                 int8_staging=staging_scales is not None,
+                per_layer=per_layer,
             )
-        use_affine = True
-        fold_affine = None
+        if pln.widths is None or pln.widths[3] >= pln.widths[0]:
+            staging_scales = None  # the plan stages at the compute width
 
-        def layer_epilogue(layer, gi, _prec=precision):
-            bias, scale, offset = quantize.fold_epilogue(
-                layer, cfg.use_batchnorm
-            )
+        def epilogue(layer, gi, _prec=precision):
+            bias, scale, offset = quantize.fold_epilogue(layer, cfg.use_batchnorm)
             if gi == 0 and _prec == "int8w":
                 # the conformed volume's fixed int8 dequant scale rides
                 # the first layer's epilogue (conv is linear in its
@@ -854,20 +956,30 @@ def meshnet_apply(
             return bias, scale, offset
 
     first = pln.segments[0]
-    p0 = pln.padded(first)
     h0 = first.halo
-    act = jnp.pad(
-        x,
-        [(0, 0)]
-        + [(h0, h0 + p - v) for p, v in zip(p0, vol)]
-        + [(0, 0)],
-    )
+    p0 = pln.padded(first)
+    act = to_planes(x, (h0, h0 + p0[0] - D), (h0, h0 + p0[1] - H))
     for i, seg in enumerate(pln.segments):
-        act = _run_segment(
-            act, seg, pln, i, params, use_affine, fold_affine, interpret,
+        layers = [
+            (params["layers"][gi]["w"],) + tuple(epilogue(params["layers"][gi], gi))
+            for gi in range(seg.start, seg.start + len(seg.dilations))
+        ]
+        head = None
+        if seg.fuse_head:
+            head = (params["head"]["w"][0, 0, 0], params["head"]["b"])
+        deq = qscale = None
+        if staging_scales is not None:
+            if seg.start > 0:
+                deq = staging_scales[seg.start - 1]
+            if not seg.fuse_head:
+                qscale = staging_scales[seg.start + len(seg.dilations) - 1]
+        act = run_segment(
+            act, seg, pln, i, layers,
+            interpret=interpret,
+            head=head,
             z_bounds=z_bounds,
-            layer_epilogue=layer_epilogue,
             compute_dtype=compute_dtype,
-            staging_scales=staging_scales,
+            deq=deq,
+            qscale=qscale,
         )
-    return act[:, :D, :H, :W, :]
+    return from_planes(act, (D, H, W, cfg.num_classes))

@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handles what the raw kernels don't: arbitrary spatial shapes (pad to block
-multiples + slice back), dtype policy, BatchNorm folding, backend dispatch
-(interpret on CPU hosts, compiled on TPU), and a kernel-backed MeshNet
+Handles what the raw kernels don't: dtype policy, BatchNorm folding, the
+per-call choice between the Pallas interpreter (CPU hosts) and compiled
+Mosaic (TPU) — ``resolve_interpret`` — and a kernel-backed MeshNet
 forward pass (`meshnet_apply`) that fuses conv+BN+ReLU per layer.
 
 ``meshnet_apply`` is the "pallas_fused" backend of the executor registry
@@ -17,25 +17,24 @@ the PAPER_MODELS sweep.
 
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import dice as dice_kernel
 from repro.kernels import dilated_conv3d as conv_kernel
 from repro.kernels import megakernel as mega_kernel
-from repro.kernels import quantize
-
-# interpret=True on CPU (this container); compiled Mosaic on real TPU.
-_INTERPRET = jax.default_backend() != "tpu"
 
 
-def _pad_to_multiple(x: jax.Array, m: int):
-    pads = [(0, (-s) % m) for s in x.shape[1:4]]
-    if not any(p[1] for p in pads):
-        return x, x.shape
-    return jnp.pad(x, [(0, 0)] + pads + [(0, 0)]), x.shape
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Whether this call runs the Pallas interpreter, decided when it is
+    made: ``None`` means interpret exactly when no TPU backs the call (the
+    CPU correctness path). On a TPU the interpreter is never chosen —
+    asking for it there is an error, not a silent slow path."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("Pallas interpret mode is a CPU path; refused on a TPU")
+    return bool(interpret)
 
 
 def dilated_conv3d(
@@ -47,22 +46,18 @@ def dilated_conv3d(
     scale=None,
     offset=None,
     fuse_affine: bool = False,
-    block: int = 16,
+    block: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """'Same' 3-D dilated conv for any (B, D, H, W, Cin)."""
-    interpret = _INTERPRET if interpret is None else interpret
+    """'Same' 3-D dilated conv for any (B, D, H, W[, Cin])."""
     if x.ndim == 4:
         x = x[..., None]
-    xp, orig_shape = _pad_to_multiple(x, block)
-    out = conv_kernel.dilated_conv3d(
-        xp, w, b,
+    return conv_kernel.dilated_conv3d(
+        x, w, b,
         dilation=dilation, scale=scale, offset=offset,
-        block=block, interpret=interpret, fuse_affine=fuse_affine,
+        block=block, interpret=resolve_interpret(interpret),
+        fuse_affine=fuse_affine,
     )
-    if xp.shape != x.shape:
-        out = out[:, : orig_shape[1], : orig_shape[2], : orig_shape[3], :]
-    return out
 
 
 def fold_batchnorm(layer: dict, eps: float = 1e-5) -> tuple[jax.Array, jax.Array]:
@@ -78,72 +73,30 @@ def meshnet_apply(
     x: jax.Array,
     cfg,
     *,
-    block: int = 16,
     interpret: bool | None = None,
     precision: str = "fp32",
 ) -> jax.Array:
-    """Kernel-backed MeshNet inference forward (== meshnet.apply, eval mode).
+    """Kernel-backed MeshNet inference forward (== meshnet.apply, eval mode),
+    the "pallas_fused" backend: the megakernel's schedule with one segment
+    per layer, so each hidden layer is ONE fused Pallas call (conv+BN+ReLU
+    epilogue) and activations make a single HBM round-trip per layer
+    instead of three; the 1x1x1 head folds into the last layer's call.
 
-    Each hidden layer is ONE fused Pallas call (conv+BN+ReLU epilogue):
-    activations make a single HBM round-trip per layer instead of three.
-
-    ``precision`` (kernels/quantize.py): "fp32" is the legacy bit-exact
-    path; "bf16" ships bf16 activations/weights with fp32 accumulate in
-    the kernel; "int8w" streams per-output-channel int8 weights whose
-    dequant scale rides the (always-fused) affine epilogue — the conv
-    bias moves into the epilogue offset because the raw accumulator is in
-    quantized-weight units. Activations stay bf16 on this per-layer path
-    (inter-layer staging is the schedule itself; only the megakernel has
-    int8 staging boundaries).
+    ``precision`` (kernels/quantize.py): "fp32" is the f32 path; "bf16"
+    ships bf16 activations/weights with fp32 accumulate in the kernel;
+    "int8w" streams per-output-channel int8 weights whose dequant scale
+    rides the (always-fused) affine epilogue. Inter-layer activations stay
+    bf16 on this per-layer path (only the megakernel stages int8).
     """
-    if x.ndim == 4:
-        x = x[..., None]
-    if precision == "fp32":
-        for i, d in enumerate(cfg.dilations):
-            layer = params["layers"][i]
-            if cfg.use_batchnorm:
-                scale, offset = fold_batchnorm(layer)
-            else:
-                scale = offset = None
-            x = dilated_conv3d(
-                x, layer["w"], layer["b"],
-                dilation=d, scale=scale, offset=offset, fuse_affine=True,
-                block=block, interpret=interpret,
-            )
-        head = params["head"]
-        # 1x1x1 head: a plain einsum (pointwise) — no spatial kernel needed.
-        return jnp.einsum("bdhwi,io->bdhwo", x, head["w"][0, 0, 0]) + head["b"]
-
-    quantize.validate(precision)
-    params = quantize.prepare_params(params, cfg, precision)
-    adt = quantize.act_dtype(precision)
-    if precision == "int8w":
-        # match the megakernel/reference rounding: the input is quantized
-        # to the conformed volume's int8 grid, then computed in bf16
-        if x.dtype != jnp.int8:
-            x = quantize.quantize_input(x)
-        x = x.astype(adt) * jnp.asarray(quantize.INPUT_SCALE, adt)
-    else:
-        x = x.astype(adt)
-    for i, d in enumerate(cfg.dilations):
-        layer = params["layers"][i]
-        bias, scale, offset = quantize.fold_epilogue(layer, cfg.use_batchnorm)
-        x = dilated_conv3d(
-            x, layer["w"], bias,
-            dilation=d, scale=scale, offset=offset, fuse_affine=True,
-            block=block, interpret=interpret,
-        )
-    head = params["head"]
-    logits = (
-        jnp.einsum(
-            "bdhwi,io->bdhwo",
-            x,
-            head["w"][0, 0, 0].astype(adt),
-            preferred_element_type=jnp.float32,
-        )
-        + head["b"].astype(jnp.float32)
+    return mega_kernel.meshnet_apply(
+        params,
+        x,
+        cfg,
+        interpret=resolve_interpret(interpret),
+        fold_affine=fold_batchnorm if cfg.use_batchnorm else None,
+        precision=precision,
+        per_layer=True,
     )
-    return logits.astype(adt)
 
 
 def meshnet_apply_megakernel(
@@ -172,13 +125,12 @@ def meshnet_apply_megakernel(
     int8w) the calibrated per-channel staging scales — see
     kernels/megakernel.py and kernels/quantize.py.
     """
-    interpret = _INTERPRET if interpret is None else interpret
     return mega_kernel.meshnet_apply(
         params,
         x,
         cfg,
         vmem_budget=vmem_budget or mega_kernel.VMEM_BUDGET,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         fold_affine=fold_batchnorm if cfg.use_batchnorm else None,
         z_bounds=z_bounds,
         precision=precision,
@@ -188,6 +140,7 @@ def meshnet_apply_megakernel(
 
 def dice(pred: jax.Array, truth: jax.Array, num_classes: int, *, interpret: bool | None = None) -> jax.Array:
     """Macro Dice score via the fused count-accumulator kernel."""
-    interpret = _INTERPRET if interpret is None else interpret
-    counts = dice_kernel.dice_counts(pred, truth, num_classes, interpret=interpret)
+    counts = dice_kernel.dice_counts(
+        pred, truth, num_classes, interpret=resolve_interpret(interpret)
+    )
     return dice_kernel.dice_from_counts(counts)

@@ -22,13 +22,11 @@ def serve_segmentation(args):
     from repro.core.pipeline import PipelineConfig
     from repro.data import mri
     from repro.serving.engine import SegmentationEngine
-    from repro.telemetry.budget import MemoryBudget
-
     shape = (args.volume,) * 3
     cfg_m = MeshNetConfig()
     params = meshnet.init(jax.random.PRNGKey(0), cfg_m)
     pc = PipelineConfig(model=cfg_m, volume_shape=shape, min_component_size=8)
-    eng = SegmentationEngine(params, pc, budget=MemoryBudget.v5e())
+    eng = SegmentationEngine(params, pc)
     key = jax.random.PRNGKey(1)
     for i in range(args.n):
         key, k = jax.random.split(key)
@@ -79,6 +77,9 @@ def main():
     ap.add_argument("--max-seq", type=int, default=64)
     ap.add_argument("--max-new", type=int, default=8)
     args = ap.parse_args()
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
     if args.engine == "segmentation":
         serve_segmentation(args)
     else:

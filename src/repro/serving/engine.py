@@ -281,7 +281,7 @@ class SegmentationEngine:
         self.params = params
         self.cfg = pipeline_cfg
         self.mask_model = mask_model
-        self.budget = budget or MemoryBudget.v5e()
+        self.budget = budget or MemoryBudget.from_device()
         self.devices = devices or getattr(pipeline_cfg, "shard_devices", None)
         self.precision = precision or getattr(pipeline_cfg, "precision", "auto")
         self._prepared: dict[str, Any] = {}

@@ -62,6 +62,26 @@ class MemoryBudget:
     def v5e() -> "MemoryBudget":
         return MemoryBudget(V5E_HBM_BYTES, name="v5e_hbm")
 
+    @staticmethod
+    def from_device(device=None) -> "MemoryBudget":
+        """The attached device's memory: ``memory_stats()["bytes_limit"]``
+        where the backend reports it. An accelerator that reports no limit
+        is an error, not a guessed size. A CPU host (no accelerator, no
+        stats) serves against the simulated v5e chip, the device the
+        budget and serving tests model."""
+        import jax
+
+        dev = device or jax.devices()[0]
+        stats = dev.memory_stats() or {}
+        if "bytes_limit" in stats:
+            return MemoryBudget(int(stats["bytes_limit"]), name=f"{dev.platform}_hbm")
+        if dev.platform == "cpu":
+            return MemoryBudget.v5e()
+        raise ValueError(
+            f"device {dev.device_kind!r} ({dev.platform}) reports no memory "
+            "limit; pass the engine an explicit MemoryBudget"
+        )
+
     # --- pricing of each strategy's peak working set ------------------------
 
     def _check(self, need: int, fail_type: str) -> None:

@@ -16,7 +16,10 @@ Modeling conventions (counted per forward):
   * a Pallas grid step re-fetches each of its input blocks — consecutive
     steps share nothing, so per-step window bytes multiply by the step
     count (this is what makes the 27-view schedule 27x and a haloed
-    window ((b+2d)/b)^3 x);
+    (Z, Y) window ((b+2d)/b)^2 x);
+  * the Pallas paths move whole padded planes: channels padded to 8
+    sublanes and rows to 128 lanes (kernels/megakernel.py's plane
+    layout), and the conversion into and out of that layout is counted;
   * weights are streamed once per grid step (tiny for MeshNet, but
     counted — at 16^3 benchmark volumes they are not negligible);
   * a batched forward (``batch=N``) re-reads and re-writes every data
@@ -32,12 +35,13 @@ Precision (kernels/quantize.py): every model takes the storage policy
 and prices each tensor role at its width — activations (fp32 4 B / bf16
 & int8w compute 2 B), weights (4/2/1 B), and for the megakernel the
 input volume and inter-segment staging (down to 1 B under int8w). The
-layer-wise backends (xla / pallas_fused / streaming) dequantize the
-input up-front, so their volume crossings are priced at the activation
-width; the megakernel is the backend whose schedule actually streams
-int8 end-to-end, which is why the int8w gate (<= 0.4x fp32 at 256^3,
-EXPERIMENTS.md H11) is stated on it. ``precision="fp32"`` reproduces the
-pre-policy numbers bit-for-bit (the regression gate compares like-for-
+XLA backends (xla / streaming) dequantize the input up-front, so their
+volume crossings are priced at the activation width; pallas_fused reads
+the int8 input but stages every layer at the activation width; the
+megakernel is the backend whose schedule streams int8 end-to-end, which
+is why the int8w gate (<= 0.4x fp32 at 256^3, EXPERIMENTS.md H11) is
+stated on it. ``precision="fp32"`` prices every role at 4 bytes (the
+regression gate compares like-for-
 like precision keys).
 
 The pluggable executor registry wires these to its specs
@@ -97,51 +101,37 @@ def dilated_conv_layer_bytes(
     cin: int,
     cout: int,
     dilation: int,
-    block: int = 16,
     dtype_bytes: int = 4,
     weight_dtype_bytes: int | None = None,
 ) -> int:
-    """One fused haloed-load conv call (kernels/dilated_conv3d.py, variant
-    "halo"): the d-halo pad round-trip, one (block+2d)^3 window DMA per
-    output block (+ the streamed weights at their own width), and the
-    fused write. The per-layer term of ``meshnet_fused_bytes``; the
-    kernels benchmark prices single conv rows with it."""
-    p = [_ceil_to(v, block) for v in vol]
-    ntiles = math.prod(pp // block for pp in p)
-    total = _vox(vol) * cin * dtype_bytes  # halo pad read...
-    total += math.prod(pp + 2 * dilation for pp in p) * cin * dtype_bytes  # + write
-    window = (block + 2 * dilation) ** 3
-    wgt = 27 * cin * cout * (weight_dtype_bytes or dtype_bytes)
-    total += ntiles * (window * cin * dtype_bytes + wgt)
-    total += math.prod(p) * cout * dtype_bytes  # fused conv+BN+ReLU write
-    return total
+    """One fused haloed-load conv call (kernels/dilated_conv3d.py), priced
+    by the one-layer plan that call runs — the conversion into the padded
+    plane layout, one haloed window DMA per output tile (+ the streamed
+    weights at their own width), the fused write, and the conversion back
+    out. The kernels benchmark prices single conv rows with it."""
+    widths = (dtype_bytes, weight_dtype_bytes or dtype_bytes, dtype_bytes, dtype_bytes)
+    return megakernel.plan(
+        (dilation,), cin, cout, 0, tuple(int(v) for v in vol), widths=widths
+    ).hbm_bytes()
 
 
 def meshnet_fused_bytes(
-    cfg, vol: Shape3, batch: int = 1, block: int = 16, precision: str = "fp32"
+    cfg, vol: Shape3, batch: int = 1, precision: str = "fp32"
 ) -> int:
-    """Per-layer fused Pallas path (ops.meshnet_apply): one
-    ``dilated_conv_layer_bytes`` term per layer, then the head einsum.
-    The per-layer weight stream (``ntiles * 27*cin*c*wb`` inside the
-    layer term) is charged once per launch, not per batch element."""
-    ab, wb = _widths(precision)
-    data = 0
-    weights = 0
-    cin = cfg.in_channels
-    c = cfg.channels
-    for d in cfg.dilations:
-        p = [_ceil_to(v, block) for v in vol]
-        wgt_l = math.prod(pp // block for pp in p) * 27 * cin * c * wb
-        data += (
-            dilated_conv_layer_bytes(
-                vol, cin, c, d, block, ab, weight_dtype_bytes=wb
-            )
-            - wgt_l
-        )
-        weights += wgt_l
-        cin = c
-    data += _vox(vol) * (c + cfg.num_classes) * ab  # head einsum
-    return batch * data + weights
+    """Per-layer fused Pallas path (ops.meshnet_apply): the megakernel's
+    planner restricted to one layer per segment, priced by its plan —
+    every layer's haloed window reads and its write at the activation
+    width (this path never stages int8), the head fused into the last
+    layer's call, the weight streams charged once per launch."""
+    pln = megakernel.plan_for_config(
+        cfg,
+        tuple(int(s) for s in vol),
+        precision=None if precision == "fp32" else precision,
+        int8_staging=False if precision == "int8w" else None,
+        batch=batch,
+        per_layer=True,
+    )
+    return pln.hbm_bytes(batch=batch)
 
 
 def meshnet_views_bytes(

@@ -60,7 +60,7 @@ def _sharded(fn, x):
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, "z", None, None, None)
-    return spatial_shard._shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec,), out_specs=spec
     )(x)
 
