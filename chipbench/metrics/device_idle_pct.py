@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device:
+100 x (1 - busy / window), busy being the union of the device's
+operation intervals (trace_reduce)."""
+
+
+def read(run):
+    t = run.trace
+    if not t or t["window_s"] <= 0 or t["busy_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
