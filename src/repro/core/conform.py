@@ -14,6 +14,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry import spans
+
 
 def _trilinear_sample(vol: jax.Array, coords: jax.Array) -> jax.Array:
     """Sample `vol` (D,H,W) at float coords (3, N) with edge clamping."""
@@ -105,14 +107,23 @@ def conform(
     the host entry point and may look at values. Malformed (non-3-D)
     payloads are NOT intercepted: they fail in resample exactly as
     before, so the serving tier's garbage-volume classification is
-    untouched."""
-    vol = jnp.asarray(vol, jnp.float32)
+    untouched.
+
+    Spans (telemetry/spans.py): ``conform.upload`` (the host array to the
+    device; it can return before the transfer ends), ``conform.range``
+    (the range check: its two blocking reads also wait for the transfer)
+    and ``conform.rescale`` (the dispatch of resample and rescale: it
+    returns before the device work ends)."""
+    with spans.span("conform.upload"):
+        vol = jnp.asarray(vol, jnp.float32)
     if vol.ndim == 3:
-        finite = jnp.where(jnp.isfinite(vol), vol, 0.0)
-        lo = float(jnp.min(finite))
-        hi = float(jnp.max(finite))
+        with spans.span("conform.range"):
+            finite = jnp.where(jnp.isfinite(vol), vol, 0.0)
+            lo = float(jnp.min(finite))
+            hi = float(jnp.max(finite))
         if not (hi - lo > 0.0):
             raise DegenerateVolumeError(lo, hi)
-    if vol.shape != out_shape:
-        vol = resample(vol, out_shape, voxel_size)
-    return rescale_intensity(vol)
+    with spans.span("conform.rescale"):
+        if vol.shape != out_shape:
+            vol = resample(vol, out_shape, voxel_size)
+        return rescale_intensity(vol)

@@ -27,14 +27,16 @@ Each stage is timed into a telemetry record, mirroring Table IV's
 per-stage columns (Preprocessing / Cropping / Inference / Merging /
 Postprocessing), and the whole run is guarded by the memory-budget model
 (telemetry/budget.py) that simulates the browser's failure modes on
-TPU-equivalent limits.
+TPU-equivalent limits. The stage timers are request-scoped spans
+(telemetry/spans.py): the same durations land in ``record.spans`` and, as
+``repro.pipeline.*`` annotations, on the profiler's trace.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
-import time
 from typing import Any, Optional
 
 import jax
@@ -44,6 +46,7 @@ from repro.core import components, conform as conform_mod, cropping, executors, 
 from repro.core.meshnet import MeshNetConfig
 from repro.core.spatial_shard import ShardGeometryError
 from repro.kernels import quantize
+from repro.telemetry import spans
 from repro.telemetry.record import StageTimes, TelemetryRecord
 from repro.telemetry.budget import MemoryBudget, BudgetExceeded
 
@@ -98,10 +101,6 @@ class PipelineResult:
     record: TelemetryRecord
 
 
-def _now() -> float:
-    return time.perf_counter()
-
-
 def _geometry_fail_type(e: ValueError) -> str:
     """Telemetry fail type for a ValueError out of the pre-flight models:
     slab-geometry problems (ShardGeometryError: non-divisible Z, missing
@@ -120,8 +119,17 @@ def run(
 ) -> PipelineResult:
     """Run the full pipeline on one raw volume. Never raises on budget
     failures — returns a failed TelemetryRecord (status='fail'), matching
-    the tool's telemetry semantics."""
-    times = StageTimes()
+    the tool's telemetry semantics. Opens a request scope
+    (telemetry/spans.py) when the caller has none open, so
+    ``record.spans`` holds this run's spans either way."""
+    scope = contextlib.nullcontext() if spans.in_request() else spans.request()
+    with scope, spans.span("pipeline.run"):
+        return _run(cfg, params, vol, mask_model, voxel_size)
+
+
+def _plan(cfg, times: StageTimes, mask_model) -> TelemetryRecord:
+    """Resolve the executor and precision and run the pre-flight byte
+    model: the run's record, failed where the schedule cannot be planned."""
     # Resolve against the geometry each forward actually sees: failsafe
     # mode runs the executor on padded cubes, not the whole volume — so
     # "auto" must judge slab divisibility / VMEM plans on the cube shape
@@ -166,6 +174,7 @@ def run(
         # the serving scheduler's fleet rollups group by. None when the
         # run is unguarded (no budget configured).
         memory_budget_bytes=None if cfg.budget is None else cfg.budget.bytes_limit,
+        spans=spans.recorded(),
     )
     try:
         # Pre-flight the sharded family's hard requirements: the host must
@@ -215,95 +224,107 @@ def run(
         # own fail type.
         rec.status = "fail"
         rec.fail_type = _geometry_fail_type(e)
+    return rec
+
+
+def _run(cfg, params, vol, mask_model, voxel_size) -> PipelineResult:
+    times = StageTimes()
+    with spans.span("pipeline.plan"):
+        rec = _plan(cfg, times, mask_model)
+    if rec.status == "fail":
         return PipelineResult(segmentation=None, record=rec)
+    precision, exec_name = rec.precision, rec.executor
     budget = cfg.budget or MemoryBudget.unlimited()
 
     act_bytes = quantize.act_bytes(precision)
     try:
         # --- Stage 1: preprocessing (conform + precision cast) --------------
-        t0 = _now()
-        x = None
-        if cfg.conform_memo is not None:
-            x = cfg.conform_memo.get(vol, cfg.volume_shape)
-        if x is None:
-            x = conform_mod.conform(vol, cfg.volume_shape, voxel_size)
+        with spans.span("pipeline.preprocess") as stage:
+            x = None
             if cfg.conform_memo is not None:
-                cfg.conform_memo.put(vol, cfg.volume_shape, x)
-        # The policy cast is conform's output write, not an inference
-        # cost: the conformed [0, 1] volume leaves preprocessing in the
-        # policy's storage dtype (int8-quantized under int8w — faithful
-        # to Brainchop, whose conformed volumes are uint8), so the
-        # inference schedule below streams it at that width.
-        if precision == "int8w":
-            x = quantize.quantize_input(x)
-        elif precision == "bf16":
-            x = x.astype(quantize.act_dtype(precision))
-        x.block_until_ready()
-        times.preprocessing = _now() - t0
+                x = cfg.conform_memo.get(vol, cfg.volume_shape)
+            if x is None:
+                x = conform_mod.conform(vol, cfg.volume_shape, voxel_size)
+                if cfg.conform_memo is not None:
+                    cfg.conform_memo.put(vol, cfg.volume_shape, x)
+            # The policy cast is conform's output write, not an inference
+            # cost: the conformed [0, 1] volume leaves preprocessing in the
+            # policy's storage dtype (int8-quantized under int8w — faithful
+            # to Brainchop, whose conformed volumes are uint8), so the
+            # inference schedule below streams it at that width.
+            if precision == "int8w":
+                x = quantize.quantize_input(x)
+            elif precision == "bf16":
+                x = x.astype(quantize.act_dtype(precision))
+            with spans.span("pipeline.preprocess.wait"):
+                x.block_until_ready()
+        times.preprocessing = stage.seconds
 
         crop_start = None
         full_shape = x.shape
         # --- Stage 2: cropping (optional) ------------------------------------
         if cfg.use_cropping and mask_model is not None:
-            t0 = _now()
-            mparams, mcfg = mask_model
-            budget.charge_inference(x.shape, mcfg, dtype_bytes=act_bytes)
-            mask_logits = executors.jitted_apply(exec_name, precision=precision)(
-                mparams, x[None], mcfg
-            )
-            mask = jnp.argmax(mask_logits[0], -1) > 0
-            mask = components.largest_component(mask)
-            size = cropping.pick_crop_size(mask, margin=cfg.crop_margin)
-            x, crop_start = cropping.crop_to(x, mask, size)
-            x.block_until_ready()
-            times.cropping = _now() - t0
+            with spans.span("pipeline.crop") as stage:
+                mparams, mcfg = mask_model
+                budget.charge_inference(x.shape, mcfg, dtype_bytes=act_bytes)
+                mask_logits = executors.jitted_apply(exec_name, precision=precision)(
+                    mparams, x[None], mcfg
+                )
+                mask = jnp.argmax(mask_logits[0], -1) > 0
+                mask = components.largest_component(mask)
+                size = cropping.pick_crop_size(mask, margin=cfg.crop_margin)
+                x, crop_start = cropping.crop_to(x, mask, size)
+                with spans.span("pipeline.crop.wait"):
+                    x.block_until_ready()
+            times.cropping = stage.seconds
             rec.crop_size = size
 
         # --- Stage 3: inference ----------------------------------------------
-        t0 = _now()
-        if cfg.mode == "subvolume":
-            budget.charge_subvolume(
-                cfg.cube, cfg.overlap, cfg.model, dtype_bytes=act_bytes
-            )
-            logits = patching.subvolume_inference(
-                x,
-                params=params,
-                model_cfg=cfg.model,
-                executor=exec_name,
-                cube=cfg.cube,
-                overlap=cfg.overlap,
-                batch_cubes=cfg.batch_cubes,
-                precision=precision,
-            )
-            logits.block_until_ready()
-            # The trimmed write-back merge happens inside subvolume_inference
-            # (host-side numpy copies, not separately timed); the whole
-            # split -> infer -> merge span is attributed to 'inference'.
-            times.inference = _now() - t0
-            times.merging = 0.0
-        elif cfg.mode == "streaming":
-            budget.charge_streaming(x.shape, cfg.model, dtype_bytes=act_bytes)
-            logits = executors.jitted_apply(exec_name, "streaming", precision)(
-                params, x[None], cfg.model
-            )[0]
-            logits.block_until_ready()
-            times.inference = _now() - t0
-        else:  # full
-            budget.charge_inference(x.shape, cfg.model, dtype_bytes=act_bytes)
-            logits = executors.jitted_apply(exec_name, precision=precision)(
-                params, x[None], cfg.model
-            )[0]
-            logits.block_until_ready()
-            times.inference = _now() - t0
+        with spans.span("pipeline.inference") as stage:
+            if cfg.mode == "subvolume":
+                budget.charge_subvolume(
+                    cfg.cube, cfg.overlap, cfg.model, dtype_bytes=act_bytes
+                )
+                logits = patching.subvolume_inference(
+                    x,
+                    params=params,
+                    model_cfg=cfg.model,
+                    executor=exec_name,
+                    cube=cfg.cube,
+                    overlap=cfg.overlap,
+                    batch_cubes=cfg.batch_cubes,
+                    precision=precision,
+                )
+                # The trimmed write-back merge happens inside
+                # subvolume_inference (host-side numpy copies, not
+                # separately timed); the whole split -> infer -> merge
+                # span is attributed to 'inference'.
+            elif cfg.mode == "streaming":
+                budget.charge_streaming(x.shape, cfg.model, dtype_bytes=act_bytes)
+                logits = executors.jitted_apply(exec_name, "streaming", precision)(
+                    params, x[None], cfg.model
+                )[0]
+            else:  # full
+                budget.charge_inference(x.shape, cfg.model, dtype_bytes=act_bytes)
+                logits = executors.jitted_apply(exec_name, precision=precision)(
+                    params, x[None], cfg.model
+                )[0]
+            with spans.span("pipeline.inference.wait"):
+                logits.block_until_ready()
+        times.inference = stage.seconds
 
-        seg = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with spans.span("pipeline.argmax"):
+            seg = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         # --- Stage 4: postprocessing (connected components) -------------------
         if cfg.postprocess:
-            t0 = _now()
-            seg = components.filter_segmentation(seg, cfg.model.num_classes, cfg.min_component_size)
-            seg.block_until_ready()
-            times.postprocessing = _now() - t0
+            with spans.span("pipeline.postprocess") as stage:
+                seg = components.filter_segmentation(
+                    seg, cfg.model.num_classes, cfg.min_component_size
+                )
+                with spans.span("pipeline.postprocess.wait"):
+                    seg.block_until_ready()
+            times.postprocessing = stage.seconds
 
         if crop_start is not None:
             seg = cropping.uncrop(seg, crop_start, full_shape)
@@ -323,7 +344,7 @@ def run(
         # payloads (wrong rank) are NOT intercepted — they still blow up
         # in resample and propagate, so the serving tier's
         # garbage-volume classification is unchanged.
-        times.preprocessing = _now() - t0
+        times.preprocessing = stage.seconds
         rec.status = "fail"
         rec.fail_type = "degenerate_volume"
         return PipelineResult(segmentation=None, record=rec)

@@ -291,8 +291,12 @@ class SegmentationEngine:
             from repro.core import spatial_shard
 
             spatial_shard.mesh_for(self.devices)
+        from repro.telemetry import spans
         from repro.telemetry.record import TelemetryLog
 
+        # collector pauses land on the trace and in the open request's
+        # spans ("gc"), so a pause inside a request is seen where it falls
+        spans.install_gc_hook()
         self.log = TelemetryLog()
         self._scheduler = None  # lazy RequestScheduler (serving/scheduler.py)
 

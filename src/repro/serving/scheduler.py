@@ -42,6 +42,7 @@ it reports is bit-reproducible in CI on CPU. DESIGN.md §5.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import time
@@ -59,6 +60,7 @@ from repro.serving.errors import (  # noqa: F401  (QueueFullError re-exported)
     TransientExecutorError,
     classify,
 )
+from repro.telemetry import spans
 from repro.telemetry.budget import BudgetExceeded, MemoryBudget
 from repro.telemetry.record import StageTimes, TelemetryRecord
 
@@ -993,36 +995,49 @@ class RequestScheduler:
                 p_service, _ = self._attempt_service(preview, p_decision, req)
                 if t + p_service > until:
                     return t, list(batch.requests[idx:])
-            result, rec, decision = self._serve_one(req, t)
-            if self.service_model is not None:
-                service, timed_out = self._attempt_service(rec, decision, req)
-                if timed_out:
-                    # the attempt is cancelled AT the bound: the member
-                    # occupied the replica for exactly the timeout, and
-                    # the fault is retryable (a retry lands on a fresh
-                    # attempt — the CHIPS stuck-job discipline)
-                    rec.status = "fail"
-                    rec.fail_type = SERVICE_TIMEOUT
-            else:
-                service = max(0.0, self.clock.now() - t)
-            finish = t + service
-            rec.request_id = req.id
-            rec.arrival_s = req.arrival_s
-            # wait = until THIS member's forward starts (batch overhead
-            # and predecessors' serialized service included), so
-            # queue_wait_s + service_s == finish - arrival exactly — the
-            # identity the SLO rollups in telemetry/analysis.py rely on.
-            # Retried attempts keep the ORIGINAL arrival, so the identity
-            # spans every attempt of a request, not just the first.
-            rec.queue_wait_s = max(0.0, t - req.arrival_s)
-            rec.service_s = service
-            rec.batch_size = len(batch.requests)
-            rec.priority_class = req.priority_class.name
-            rec.demoted = req.demoted
-            rec.attempt = req.attempt
-            self._finish_attempt(req, rec, result, finish)
+            with self._member_scope(req.id, len(batch.requests)):
+                result, rec, decision = self._serve_one(req, t)
+                if self.service_model is not None:
+                    service, timed_out = self._attempt_service(rec, decision, req)
+                    if timed_out:
+                        # the attempt is cancelled AT the bound: the member
+                        # occupied the replica for exactly the timeout, and
+                        # the fault is retryable (a retry lands on a fresh
+                        # attempt — the CHIPS stuck-job discipline)
+                        rec.status = "fail"
+                        rec.fail_type = SERVICE_TIMEOUT
+                else:
+                    service = max(0.0, self.clock.now() - t)
+                finish = t + service
+                rec.request_id = req.id
+                rec.arrival_s = req.arrival_s
+                # wait = until THIS member's forward starts (batch overhead
+                # and predecessors' serialized service included), so
+                # queue_wait_s + service_s == finish - arrival exactly — the
+                # identity the SLO rollups in telemetry/analysis.py rely on.
+                # Retried attempts keep the ORIGINAL arrival, so the identity
+                # spans every attempt of a request, not just the first.
+                rec.queue_wait_s = max(0.0, t - req.arrival_s)
+                rec.service_s = service
+                rec.batch_size = len(batch.requests)
+                rec.priority_class = req.priority_class.name
+                rec.demoted = req.demoted
+                rec.attempt = req.attempt
+                self._finish_attempt(req, rec, result, finish)
             t = finish
         return t, []
+
+    @contextlib.contextmanager
+    def _member_scope(self, rid: int, group: int):
+        """Real execution: one request scope per member, timed as
+        ``sched.member`` (telemetry/spans.py) from its service to its
+        terminal bookkeeping. The modeled path runs on a virtual clock
+        and opens none."""
+        if not self.execute:
+            yield
+            return
+        with spans.request(rid), spans.span("sched.member", group=group):
+            yield
 
     def _run_batched_launch(
         self, batch: Batch, until: Optional[float], t: float
@@ -1364,6 +1379,7 @@ class RequestScheduler:
                 executor=key.executor if key else None,
                 precision=key.precision if key else None,
                 fail_type=classify(e),
+                spans=spans.recorded(),
                 extra={"error": f"{type(e).__name__}: {e}"},
             )
             self.engine.log.append(rec)

@@ -98,6 +98,11 @@ class TelemetryRecord:
     # (replica_id, request_id) and taking the last attempt reconstructs
     # each request's terminal state from the stream alone.
     attempt: int = 0
+    # host seconds of each span this request opened (telemetry/spans.py),
+    # keyed by span name without its trace prefix: the pipeline's stages,
+    # conform's steps, the scheduler's member, collector pauses ("gc").
+    # Empty on modeled (execute=False) records.
+    spans: dict = dataclasses.field(default_factory=dict)
     extra: dict = dataclasses.field(default_factory=dict)
 
     def to_json(self) -> str:
