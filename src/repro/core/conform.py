@@ -4,7 +4,11 @@ paper), resample to 1 mm isotropic, and rescale intensities to uint8-like
 [0, 255] with robust quantile clipping.
 
 Pure JAX (trilinear resampling via gather), jit-able with static output
-shape, so it can run on-device as stage 1 of the pipeline.
+shape, so it can run on-device as stage 1 of the pipeline. The clipping
+quantiles are those of ``jnp.quantile``'s ``linear`` method, value for
+value, but their order statistics come from an exact selection by
+counting (``_kth_smallest``) in place of the full sort ``jnp.quantile``
+lowers to: a sort of 16.7M voxels that keeps four of them.
 """
 
 from __future__ import annotations
@@ -13,6 +17,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from repro.telemetry import spans
 
@@ -65,13 +71,76 @@ def resample(vol: jax.Array, out_shape: tuple[int, int, int], voxel_size=(1.0, 1
     return _trilinear_sample(src, coords).reshape(out_shape)
 
 
+_SIGN = np.uint32(0x80000000)
+
+
+def _order_keys(vol: jax.Array) -> jax.Array:
+    """float32 -> uint32 keys in the values' order: the bits of a negative
+    value flipped, the sign bit of a non-negative one set. A value equal
+    to zero is keyed as ``+0.0`` first, as the comparator of the sort in
+    ``jnp.quantile`` canonicalizes it: ``-0.0``, and subnormals where the
+    backend compares them as zero (the CPU and the TPU do). No NaN may be
+    given."""
+    bits = lax.bitcast_convert_type(jnp.where(vol == 0.0, 0.0, vol), jnp.uint32)
+    return jnp.where(bits >= _SIGN, ~bits, bits | _SIGN)
+
+
+def _key_values(keys: jax.Array) -> jax.Array:
+    """The inverse of ``_order_keys``."""
+    bits = jnp.where(keys >= _SIGN, keys ^ _SIGN, ~keys)
+    return lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _kth_smallest(keys: jax.Array, ranks: jax.Array) -> jax.Array:
+    """The ``ranks``-th smallest (0-based int32, shape (T,)) of ``keys``.
+
+    Exact radix selection by counting, from the top bit down: the answer
+    for rank r is the largest t with count(keys < t) <= r, and each of the
+    32 passes settles one bit of it for every rank at once. A pass is one
+    fused reduction over the keys (their count below each candidate): no
+    sort, and no shape that depends on the data."""
+
+    def settle(i, prefix):
+        cand = prefix + lax.shift_left(np.uint32(1), (31 - i).astype(jnp.uint32))
+        below = jnp.stack([jnp.sum(keys < c) for c in cand])
+        return jnp.where(below <= ranks, cand, prefix)
+
+    return lax.fori_loop(0, 32, settle, jnp.zeros(ranks.shape, jnp.uint32))
+
+
+def _linear_positions(q, size: int):
+    """``jnp.quantile``'s ``linear`` method for one ``q`` over ``size``
+    values, op for op in float32 (jax 0.9.0, ``_quantile``): the ranks of
+    the two order statistics it interpolates, and their weights."""
+    q = jnp.asarray(q, jnp.float32)
+    n = lax.convert_element_type(size, jnp.float32)
+    q = lax.mul(q, n - 1)
+    low, high = lax.floor(q), lax.ceil(q)
+    high_weight = lax.sub(q, low)
+    low_weight = lax.sub(np.float32(1), high_weight)
+    low = lax.convert_element_type(lax.clamp(np.float32(0), low, n - 1), jnp.int32)
+    high = lax.convert_element_type(lax.clamp(np.float32(0), high, n - 1), jnp.int32)
+    return low, high, low_weight, high_weight
+
+
 @jax.jit
 def rescale_intensity(vol: jax.Array, lo_q: float = 0.01, hi_q: float = 0.99) -> jax.Array:
     """Robust rescale to [0, 1] by quantile clipping (conform's uint8 rescale,
-    kept in float). Also zeroes non-finite voxels ("eliminate noisy voxels")."""
+    kept in float). Also zeroes non-finite voxels ("eliminate noisy voxels").
+
+    The quantiles equal ``jnp.quantile(vol, q)`` (``method="linear"``): the
+    same float32 ops give the ranks, weights and interpolation, and the two
+    order statistics each needs come from ``_kth_smallest``, exact. Where
+    its sort would return ``-0.0`` (or a subnormal that compares as zero)
+    this returns ``+0.0``, as ``_order_keys`` folds them, which rescales
+    every voxel to the same value."""
     vol = jnp.where(jnp.isfinite(vol), vol, 0.0)
-    lo = jnp.quantile(vol, lo_q)
-    hi = jnp.quantile(vol, hi_q)
+    lo_low, lo_high, lo_low_w, lo_high_w = _linear_positions(lo_q, vol.size)
+    hi_low, hi_high, hi_low_w, hi_high_w = _linear_positions(hi_q, vol.size)
+    ranks = jnp.stack([lo_low, lo_high, hi_low, hi_high])
+    stat = _key_values(_kth_smallest(_order_keys(vol), ranks))
+    lo = lax.add(lax.mul(stat[0], lo_low_w), lax.mul(stat[1], lo_high_w))
+    hi = lax.add(lax.mul(stat[2], hi_low_w), lax.mul(stat[3], hi_high_w))
     out = (vol - lo) / jnp.maximum(hi - lo, 1e-6)
     return jnp.clip(out, 0.0, 1.0)
 

@@ -11,6 +11,7 @@ pytest-xdist every worker imports this file.
 """
 
 import os
+import re
 import time
 
 import jax
@@ -18,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import meshnet
+from repro.core import conform, meshnet
 from repro.core.meshnet import PAPER_MODELS
 from repro.kernels import dice, megakernel, ops
 from repro.kernels import dilated_conv3d as conv_kernel
@@ -119,3 +120,20 @@ def test_dice_compiles_for_v5e(v5e):
     v = jax.ShapeDtypeStruct(PAPER_VOLUME, jnp.int32, sharding=v5e)
     compiled, _ = _compile(lambda a, b: dice.dice_counts(a, b, 3), v, v)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: an HLO sort instruction, as ``%sort.9 = f32[16777216]{0} sort(...)``
+SORT_INSTRUCTION = re.compile(r"\ssort\(")
+
+
+def test_conform_rescale_compiles_without_a_sort_for_v5e(v5e):
+    # conform's quantiles come from exact selection: a full sort of the
+    # 256^3 volume (~43 ms a request on a v5e) must not come back
+    x = jax.ShapeDtypeStruct(PAPER_VOLUME, jnp.float32, sharding=v5e)
+    compiled, secs = _compile(conform.rescale_intensity, x)
+    assert not SORT_INSTRUCTION.search(compiled.as_text())
+    assert secs < COMPILE_SECONDS_BOUND, secs
+    # the pattern finds the sort jnp.quantile lowers to
+    small = jax.ShapeDtypeStruct((16, 16, 16), jnp.float32, sharding=v5e)
+    sorted_q, _ = _compile(lambda v: jnp.quantile(v, 0.01), small)
+    assert SORT_INSTRUCTION.search(sorted_q.as_text())
