@@ -14,7 +14,8 @@ One process drives the chip(s); it starts no child. Weights come from
     bf16, with connected-components postprocessing on, as served;
   * 1 request forced to ``executor="pallas_fused"`` (bf16);
   * 1 ``subvolume_gwm_failsafe`` request in ``mode="subvolume"`` (cube
-    64, overlap 46), which must run ``pallas_megakernel`` at int8w.
+    64, overlap 46) with ``precision="int8w"`` named (``auto`` serves
+    bf16), which must run ``pallas_megakernel``.
 
 Every request must come back ``status == "ok"`` with the expected
 executor, precision and mode, with nothing demoted, faulted or shed by
@@ -306,7 +307,7 @@ def one_chip(seed: int) -> None:
     eng_fs = SegmentationEngine(
         p_fs,
         PipelineConfig(name="subvolume_gwm_failsafe", model=failsafe,
-                       volume_shape=VOLUME, executor="auto", precision="auto",
+                       volume_shape=VOLUME, executor="auto", precision="int8w",
                        cube=cube, overlap=overlap, postprocess=False),
     )
     (c,) = serve(

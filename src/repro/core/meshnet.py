@@ -71,6 +71,10 @@ class MeshNetConfig:
 # Layer counts in Table IV count BN/activation stages; here a "layer" is one
 # conv block. 5.6k ~= channels=5 GWM; 23k ~= channels=10 "large"; the
 # failsafe/subvolume variants use wider channels (96k ~= 21ch).
+# atlas_104 is Table IV's 86,372 exactly under param_count: 21 channels and
+# 8 convs (588 + 7 * 11,928 + 2,288), the only width and depth that give it.
+# The paper states no schedule for 8 convs; this one is Table I's less its
+# last layer (dilations move neither the params nor the FLOPs, only halos).
 PAPER_MODELS = {
     "gwm_light": MeshNetConfig(channels=5, num_classes=3),
     "gwm_large": MeshNetConfig(channels=10, num_classes=3),
@@ -79,7 +83,9 @@ PAPER_MODELS = {
     "extract_brain_fast": MeshNetConfig(channels=5, num_classes=2),
     "subvolume_gwm_failsafe": MeshNetConfig(channels=21, num_classes=3),
     "atlas_50": MeshNetConfig(channels=10, num_classes=50),
-    "atlas_104": MeshNetConfig(channels=18, num_classes=104),
+    "atlas_104": MeshNetConfig(
+        channels=21, num_classes=104, dilations=(1, 2, 4, 8, 16, 8, 4, 2)
+    ),
 }
 
 

@@ -15,8 +15,8 @@ the slab count for any executor). The default ``"auto"`` resolves per
 host: the sharded megakernel on multi-device TPU when the per-slab tile
 plan fits VMEM, the megakernel on one TPU device, else the fused kernel;
 XLA on CPU hosts. ``PipelineConfig.precision`` picks the storage policy
-(kernels/quantize.py: fp32 | bf16 | int8w; "auto" -> bf16 on TPU, int8w
-for wide models, fp32 on CPU) — the conformed volume leaves
+(kernels/quantize.py: fp32 | bf16 | int8w; "auto" -> bf16 on TPU at every
+width, fp32 on CPU) — the conformed volume leaves
 preprocessing in the policy's storage dtype and every backend runs its
 precision-matched kernels. The executor and precision that actually ran
 — plus the modeled HBM, inter-device halo, and streamed-weight bytes
@@ -73,8 +73,8 @@ class PipelineConfig:
     # sharded form (streaming) keep running single-device.
     shard_devices: Optional[int] = None
     # storage policy (kernels/quantize.py): "fp32" | "bf16" | "int8w" |
-    # "auto" ("auto" -> bf16 on TPU, int8w for wide models, fp32 on CPU
-    # hosts where the XLA oracle serves). The conformed volume is cast /
+    # "auto" ("auto" -> bf16 on TPU at every width, fp32 on CPU hosts
+    # where the XLA oracle serves). The conformed volume is cast /
     # int8-quantized once at the end of preprocessing, so the inference
     # schedule streams the policy's storage dtypes end to end; the
     # resolved policy and the weight footprint are stamped on telemetry.
@@ -99,6 +99,13 @@ class PipelineConfig:
 class PipelineResult:
     segmentation: Optional[jax.Array]
     record: TelemetryRecord
+
+
+@jax.jit
+def labels(logits: jax.Array) -> jax.Array:
+    """int32 class labels of (..., num_classes) logits, the first index
+    on a tie: one dispatch, named ``jit_labels`` in the device trace."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _geometry_fail_type(e: ValueError) -> str:
@@ -140,7 +147,7 @@ def _plan(cfg, times: StageTimes, mask_model) -> TelemetryRecord:
         if cfg.mode == "subvolume"
         else cfg.volume_shape
     )
-    precision = quantize.resolve_precision(cfg.precision, cfg.model)
+    precision = quantize.resolve_precision(cfg.precision)
     exec_name = executors.resolve(cfg.executor, cfg.model, work_shape, precision)
     if cfg.shard_devices is not None:
         inner = executors.inner_of(exec_name)
@@ -314,7 +321,7 @@ def _run(cfg, params, vol, mask_model, voxel_size) -> PipelineResult:
         times.inference = stage.seconds
 
         with spans.span("pipeline.argmax"):
-            seg = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            seg = labels(logits)
 
         # --- Stage 4: postprocessing (connected components) -------------------
         if cfg.postprocess:

@@ -113,30 +113,20 @@ def staging_bytes(precision: str) -> int:
     return _STAGING_BYTES[validate(precision)]
 
 
-def resolve_precision(
-    name: Optional[str],
-    model: Any = None,
-    *,
-    backend: Optional[str] = None,
-) -> str:
-    """Map None/"auto" to the device+model default; validate explicit names.
+def resolve_precision(name: Optional[str], *, backend: Optional[str] = None) -> str:
+    """Map None/"auto" to the device default; validate explicit names.
 
     Policy: CPU hosts serve fp32 — the Pallas paths there are interpret-
     mode correctness tools and the XLA fp32 graph is the oracle every
-    parity test compares against. TPU serves bf16 by default (the 2x
-    byte cut is numerically free at our parity bound), stepping up to
-    int8w for the wide failsafe/atlas models (channels >= 16) whose
-    weight taps and staging volumes are large enough that the extra
-    quantization machinery pays for itself. An explicit name always wins.
+    parity test compares against. TPU serves bf16 for every model, at
+    every width (the 2x byte cut is numerically free at our parity
+    bound). int8w is served only when named: against the fp32 reference
+    it does not yet agree (ROADMAP C.9). An explicit name always wins.
     """
     if name is not None and name != AUTO:
         return validate(name)
     backend = backend or jax.default_backend()
-    if backend != "tpu":
-        return "fp32"
-    if model is not None and getattr(model, "channels", 0) >= 16:
-        return "int8w"
-    return "bf16"
+    return "bf16" if backend == "tpu" else "fp32"
 
 
 # ------------------------------------------------------------- weights ---

@@ -306,7 +306,7 @@ class SegmentationEngine:
         footprint is what TelemetryRecord.params_bytes tracks)."""
         from repro.kernels import quantize
 
-        resolved = quantize.resolve_precision(precision, self.cfg.model)
+        resolved = quantize.resolve_precision(precision)
         if resolved not in self._prepared:
             self._prepared[resolved] = quantize.prepare_params(
                 self.params, self.cfg.model, resolved
@@ -321,9 +321,7 @@ class SegmentationEngine:
         from repro.kernels import quantize
         from repro.telemetry.budget import BudgetExceeded
 
-        resolved = quantize.resolve_precision(
-            precision or self.precision, self.cfg.model
-        )
+        resolved = quantize.resolve_precision(precision or self.precision)
         try:
             self.budget.charge_streaming(
                 volume_shape, self.cfg.model,

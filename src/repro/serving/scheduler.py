@@ -434,9 +434,7 @@ class RequestScheduler:
         # input (which conform reshapes anyway).
         if not self.cfg.native_shapes:
             shape = tuple(int(s) for s in eng.cfg.volume_shape)
-        precision = quantize.resolve_precision(
-            req.precision or eng.precision, eng.cfg.model
-        )
+        precision = quantize.resolve_precision(req.precision or eng.precision)
         mode = req.mode or eng.pick_mode(shape, precision)
         work_shape = (
             (eng.cfg.cube + 2 * eng.cfg.overlap,) * 3

@@ -387,7 +387,7 @@ class TestPipelineAndEngine:
         )
         assert pc.precision == "auto"
         res = pipeline.run(pc, params, vol)
-        want = quantize.resolve_precision("auto", cfg)
+        want = quantize.resolve_precision("auto")
         assert res.record.precision == want
 
     def test_engine_per_request_precision_and_prepared_cache(self):

@@ -27,6 +27,8 @@ from repro.kernels import dilated_conv3d as conv_kernel
 PAPER_VOLUME = (256, 256, 256)
 #: one sub-volume cube of the failsafe: 64 + 2 * 46 (the RF radius)
 CUBE_WINDOW = (156, 156, 156)
+#: the brain crop the atlas models run on (core/cropping.py's ladder)
+BRAIN_CROP = (192, 192, 192)
 #: a compile that took minutes (and ~14 GB of host memory) before the
 #: tap loop became one matmul per row block takes about a second now
 COMPILE_SECONDS_BOUND = 60.0
@@ -95,6 +97,17 @@ def test_megakernel_compiles_for_v5e(v5e, name, vol, precision):
     compiled, secs, pln = _meshnet(v5e, name, vol, precision)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= len(pln.segments)
+    assert secs < COMPILE_SECONDS_BOUND, secs
+
+
+def test_atlas_104_megakernel_compiles_for_v5e(v5e):
+    # 21 channels ride 24 sublanes and the head writes 104 logits a voxel:
+    # the plan takes four segments, the head fused into the last
+    compiled, secs, pln = _meshnet(v5e, "atlas_104", BRAIN_CROP, "bf16")
+    assert [s.dilations for s in pln.segments] == [(1, 2, 4, 8), (16,), (8,), (4, 2)]
+    assert [s.fuse_head for s in pln.segments] == [False, False, False, True]
+    assert pln.segments[-1].num_classes == 104
+    assert compiled.as_text().count("tpu_custom_call") >= len(pln.segments)
     assert secs < COMPILE_SECONDS_BOUND, secs
 
 
