@@ -333,6 +333,28 @@ def modeled_hbm_bytes(
     return spec.hbm_bytes(cfg, volume_shape, batch=batch, precision=precision)
 
 
+def modeled_recompute(
+    name: Optional[str],
+    cfg: MeshNetConfig,
+    volume_shape: tuple[int, int, int],
+    precision: str = "fp32",
+) -> Optional[float]:
+    """How far the named executor's megakernel plan computes past the
+    lane-padded volume (``MegakernelPlan.recompute``), or None for a
+    backend that runs no such plan. Host only: the plan is memoized."""
+    name = resolve(name, cfg, volume_shape, precision)
+    if name not in ("pallas_megakernel", "pallas_fused"):
+        return None
+    per_layer = name == "pallas_fused"
+    return megakernel.plan_for_config(
+        cfg,
+        volume_shape,
+        precision=None if precision == "fp32" else precision,
+        int8_staging=False if per_layer and precision == "int8w" else None,
+        per_layer=per_layer,
+    ).recompute
+
+
 def modeled_collective_bytes(
     name: Optional[str],
     cfg: MeshNetConfig,

@@ -207,11 +207,17 @@ def _plan(cfg, times: StageTimes, mask_model) -> TelemetryRecord:
                 exec_name, cfg.model, cube_shape, precision=precision
             )
             rec.hbm_bytes_modeled = None if per_cube is None else ncubes * per_cube
+            rec.recompute_modeled = executors.modeled_recompute(
+                exec_name, cfg.model, cube_shape, precision=precision
+            )
             rec.collective_bytes_modeled = ncubes * executors.modeled_collective_bytes(
                 exec_name, cfg.model, cube_shape, precision=precision
             )
         else:
             rec.hbm_bytes_modeled = executors.modeled_hbm_bytes(
+                exec_name, cfg.model, cfg.volume_shape, precision=precision
+            )
+            rec.recompute_modeled = executors.modeled_recompute(
                 exec_name, cfg.model, cfg.volume_shape, precision=precision
             )
             rec.collective_bytes_modeled = executors.modeled_collective_bytes(

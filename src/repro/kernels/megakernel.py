@@ -54,11 +54,20 @@ stack into consecutive *segments*, each run depth-first with its own
 one segment per layer is the per-layer fused path (``pallas_fused``,
 ``plan_for_config(per_layer=True)``); one segment for the whole stack is
 the pure megakernel. The planner chooses segment boundaries and (Z, Y)
-tile shapes by dynamic programming over the modeled HBM traffic, subject
+tile shapes by dynamic programming over the modeled device time, subject
 to ``_segment_vmem_bytes`` — every buffer priced at the bytes it really
 occupies in VMEM, padded rows and lanes included — staying under the
-budget. ``MegakernelPlan.hbm_bytes`` is the traffic model the benchmarks
-and telemetry report (telemetry/traffic.py).
+budget.
+
+The time model is ``computed positions x T_POS + HBM bytes / HBM_BW``.
+On the chip the kernel's time follows the positions its layers evaluate,
+halo rings and clamped row blocks included, far more than its bytes: a
+deep segment skips an HBM round trip but fits VMEM only on small tiles,
+so every layer recomputes the rest of the segment's halo. Pricing bytes
+alone chose deep segments that computed every voxel ~2-3 times.
+``MegakernelPlan.hbm_bytes`` stays the traffic model the benchmarks and
+telemetry report (telemetry/traffic.py); ``MegakernelPlan.recompute`` is
+how far a plan computes past the lane-padded volume.
 """
 
 from __future__ import annotations
@@ -91,6 +100,17 @@ VMEM_HEADROOM = 16 * 1024 * 1024
 
 #: Cap on the f32 im2col operand of one row block (27 stacked tap slices).
 STACK_BYTES = 2 * 1024 * 1024
+
+#: HBM bandwidth of one v5e chip, B/s (Google Cloud, "TPU v5e": 819 GB/s);
+#: the planner's price of a byte moved.
+HBM_BW = 819e9
+
+#: Device seconds one computed position costs per input sublane of its
+#: layer (the 27 taps' load, cast, roll, mask and stack, and the matmul):
+#: fitted on a v5e to the per-segment device times of the served bf16
+#: plans of gwm_light, gwm_large (256^3) and atlas_104 (192^3), 8 to 24
+#: sublanes, less their bytes at ``HBM_BW`` (PERF.md §5).
+T_POS = 35e-12
 
 #: Per-axis (Z, Y) tile-size candidates. Neither axis meets an alignment
 #: rule (Z is a leading dim, a Y row is a whole lane-tile multiple), so
@@ -178,6 +198,41 @@ def _row_block(s_y: int, cin: int, xp: int) -> int:
     return max(1, min(s_y, STACK_BYTES // per_row))
 
 
+def _ntiles(seg: Segment, padded: tuple[int, int]) -> int:
+    """Grid steps of ``seg`` over its tile-multiple (Z, Y) region."""
+    return math.prod(pp // t for pp, t in zip(padded, seg.tile))
+
+
+def _tile_positions(seg: Segment, width: int) -> tuple[int, int]:
+    """(conv, head) work of one grid step of ``seg`` on a ``width``-wide
+    volume, as the kernel loops: each layer evaluates every plane of its
+    buffer in blocks of ``_row_block`` rows (the clamped last block
+    counted whole), ``lanes(width)`` positions a row. A conv position
+    counts ``rows(cin)`` — its input sublanes; the fused head counts the
+    last layer's positions once."""
+    xp = lanes(width)
+    conv = head = 0
+    for li, s in enumerate(seg.buffer_sizes()[1:]):
+        cin = seg.cin if li == 0 else seg.channels
+        r = _row_block(s[1], cin, xp)
+        pos = s[0] * -(-s[1] // r) * r * xp
+        conv += pos * rows(cin)
+        head = pos
+    return conv, head if seg.fuse_head else 0
+
+
+def _segment_seconds(seg: Segment, padded: tuple[int, int], width: int,
+                     hbm_bytes: int, batch: int = 1) -> float:
+    """Modeled device seconds of one segment: its computed positions at
+    ``T_POS`` plus ``hbm_bytes`` at ``HBM_BW``. A fused head is the
+    matmul it is: ``rows(num_classes) x rows(C)`` a position, against a
+    conv's ``rows(C) x 27 * rows(cin)`` that costs ``rows(cin)``, so
+    ``rows(num_classes) / 27`` sublanes."""
+    conv, head = _tile_positions(seg, width)
+    work = conv + head * rows(seg.num_classes) / 27
+    return batch * _ntiles(seg, padded) * work * T_POS + hbm_bytes / HBM_BW
+
+
 def _segment_vmem_bytes(
     seg: Segment,
     dtype_bytes: int = 4,
@@ -247,10 +302,9 @@ def _segment_hbm_bytes(
     padded rows of ``rows(cin)`` planes), per-spatial-tile weight
     streams, and the central-region write. The ONE formula shared by
     ``MegakernelPlan.hbm_bytes`` (what telemetry/benchmarks report) and
-    the planner's DP objective — so the plan the DP picks is the minimum
-    of the model it reports (int8 staging aside: the DP prices staging
-    at the compute width, ``plan``). ``widths`` prices each tensor role at its
-    policy byte width.
+    the bytes term of the planner's time objective (int8 staging aside:
+    the DP prices staging at the compute width, ``plan``). ``widths``
+    prices each tensor role at its policy byte width.
 
     ``batch`` scales only the data terms, NOT the weight stream: the
     launch grid iterates batch innermost, so each segment's weight blocks
@@ -262,7 +316,7 @@ def _segment_hbm_bytes(
     ib = inp if seg.start == 0 else stg
     ob = act if seg.fuse_head else stg
     ci, c = rows(seg.cin), rows(seg.channels)
-    ntiles = math.prod(pp // t for pp, t in zip(padded, seg.tile))
+    ntiles = _ntiles(seg, padded)
     window = math.prod(t + 2 * seg.halo for t in seg.tile) * xp
     wgt = 27 * ci * c * wt + 27 * c * c * wt * (len(seg.dilations) - 1)
     if seg.fuse_head:
@@ -270,6 +324,20 @@ def _segment_hbm_bytes(
     data = ntiles * window * ci * ib
     data += math.prod(padded) * xp * rows(seg.cout) * ob
     return batch * data + ntiles * wgt
+
+
+def _in_bytes(seg: Segment, padded: tuple[int, int], vol, inp: int, batch: int) -> int:
+    """HBM bytes of the input's conversion into the plane layout of the
+    first segment: the read, and the write padded by its halo."""
+    window = math.prod(p + 2 * seg.halo for p in padded) * lanes(vol[2])
+    return batch * (math.prod(vol) * seg.cin + window * rows(seg.cin)) * inp
+
+
+def _out_bytes(seg: Segment, padded: tuple[int, int], vol, act: int, batch: int) -> int:
+    """HBM bytes of the logits' conversion out of the plane layout of the
+    last segment: the padded read and the write."""
+    plane = math.prod(padded) * lanes(vol[2]) * rows(seg.cout)
+    return batch * (plane + math.prod(vol) * seg.cout) * act
 
 
 @dataclasses.dataclass(frozen=True)
@@ -318,19 +386,35 @@ class MegakernelPlan:
         own per-role widths (``dtype_bytes`` is the legacy uniform knob
         and is ignored when ``widths`` is set)."""
         widths = self.widths or (dtype_bytes,) * 4
-        act, inp = widths[0], widths[2]
-        xp = self.xp
         first, last = self.segments[0], self.segments[-1]
-        p0 = self.padded(first)
-        total = batch * math.prod(self.vol) * first.cin * inp
-        total += batch * math.prod(t + 2 * first.halo for t in p0) * xp * rows(first.cin) * inp
+        total = _in_bytes(first, self.padded(first), self.vol, widths[2], batch)
         for seg in self.segments:
             total += _segment_hbm_bytes(
                 seg, self.padded(seg), self.vol[2], dtype_bytes, widths, batch=batch
             )
-        total += batch * math.prod(self.padded(last)) * xp * rows(last.cout) * act
-        total += batch * math.prod(self.vol) * last.cout * act
-        return total
+        return total + _out_bytes(last, self.padded(last), self.vol, widths[0], batch)
+
+    def seconds(self, batch: int = 1) -> float:
+        """Modeled device seconds of one batched forward: every segment's
+        computed positions at ``T_POS``, and ``hbm_bytes`` at ``HBM_BW``."""
+        work = sum(
+            _segment_seconds(seg, self.padded(seg), self.vol[2], 0, batch)
+            for seg in self.segments
+        )
+        return work + self.hbm_bytes(batch) / HBM_BW
+
+    @property
+    def recompute(self) -> float:
+        """Conv positions the plan computes over those of the lane-padded
+        volume (layers x Z x Y x ``xp`` x ``rows(cin)``): 1.0 computes each
+        voxel once. X's lane padding, which no plan removes, is not
+        counted; neither is the fused head."""
+        done = need = 0
+        for seg in self.segments:
+            done += _ntiles(seg, self.padded(seg)) * _tile_positions(seg, self.vol[2])[0]
+            cins = (seg.cin,) + (seg.channels,) * (len(seg.dilations) - 1)
+            need += sum(self.vol[0] * self.vol[1] * self.xp * rows(c) for c in cins)
+        return done / need
 
 
 def plan(
@@ -348,23 +432,26 @@ def plan(
     per_layer: bool = False,
     widths: Optional[Widths] = None,
 ) -> MegakernelPlan:
-    """Choose segment boundaries and (Z, Y) tiles by DP over modeled HBM
-    traffic, subject to each segment's working set fitting VMEM.
+    """Choose segment boundaries and (Z, Y) tiles by DP over modeled
+    device time (``MegakernelPlan.seconds``: computed positions at
+    ``T_POS``, HBM bytes at ``HBM_BW``), subject to each segment's
+    working set fitting VMEM.
 
     ``precision`` prices every tensor role at its policy width
     (``plan_widths``): a bf16/int8 working set is smaller, so the DP
-    affords larger tiles and fewer halo re-fetches on top of the
-    per-byte cut. ``precision=None`` keeps the legacy uniform-
-    ``dtype_bytes`` model. Under int8 staging the DP still prices the
-    staging streams at the compute width: every int8 boundary is a lossy
-    requantization, so the plan keeps the boundaries of the lossless
-    schedule and only narrows them (the plan reports its true bytes).
+    affords larger tiles. ``precision=None`` keeps the legacy uniform-
+    ``dtype_bytes`` model. Under int8 staging the DP minimizes the HBM
+    bytes alone, with the staging streams priced at the compute width:
+    every int8 boundary is a lossy requantization, so the plan keeps the
+    boundaries of the lossless byte-optimal schedule and only narrows
+    them (the plan reports its true bytes); a time objective would buy
+    more boundaries, and with them more rounding.
 
     ``batch`` co-optimizes the tile shape against the batch size: the DP
-    objective scales the data terms by N while charging the weight stream
-    once per launch. The VMEM constraint is unchanged — the grid iterates
-    one (tile, batch element) at a time, so a plan feasible at batch 1
-    stays feasible at any batch.
+    objective scales the data terms and the computed positions by N
+    while charging the weight stream once per launch. The VMEM constraint
+    is unchanged — the grid iterates one (tile, batch element) at a time,
+    so a plan feasible at batch 1 stays feasible at any batch.
 
     ``per_layer`` restricts every segment to one layer: the per-layer
     fused schedule (``pallas_fused``) is this plan. ``num_classes=0``
@@ -404,12 +491,14 @@ def _plan_cached(
 ) -> MegakernelPlan:
     n = len(dils)
     act, _, inp, stg = widths
-    # Segment boundaries are priced at the compute width: an int8 staging
-    # boundary requantizes the activations (lossy, unlike a bf16 one), so
-    # int8 may narrow the streams of the boundaries the lossless schedule
-    # takes but never buys an extra one. Feasibility uses the real widths.
+    # An int8 staging boundary requantizes the activations (lossy, unlike
+    # a bf16 one), so int8 may narrow the streams of the boundaries the
+    # lossless byte-optimal schedule takes but never buys an extra one:
+    # such plans minimize bytes, staging priced at the compute width.
+    # Every other plan minimizes modeled time. Feasibility uses the real
+    # widths.
+    by_bytes = stg < act
     cost_widths = widths[:3] + (max(stg, act),)
-    xp = lanes(vol[2])
     # Oversize tiles only waste padding: cap candidates near the volume.
     cands = [
         [t for t in TILE_CANDIDATES if t <= _ceil_to(v, 8)] or [8]
@@ -429,15 +518,14 @@ def _plan_cached(
 
     # DP over segment boundaries; each (i, j) picks its cheapest feasible
     # tile. Segments are short (<= n layers) and the tile grid is ~14^2,
-    # so pricing each candidate with the exact per-segment formulas keeps
-    # the DP objective and the reported model one formula.
+    # so each candidate is priced with the exact per-segment formulas the
+    # plan reports.
     INF = float("inf")
     best: list[float] = [INF] * (n + 1)
     best[n] = 0.0
     choice: list[tuple[int, tuple[int, int]] | None] = [None] * (n + 1)
     for i in range(n - 1, -1, -1):
         for j in range(i + 1, (i + 2 if per_layer else n + 1)):
-            h = sum(dils[i:j])
             top = None
             for tz in cands[0]:
                 for ty in cands[1]:
@@ -445,18 +533,13 @@ def _plan_cached(
                     if _segment_vmem_bytes(seg, widths=widths, width=vol[2]) > vmem_budget:
                         continue
                     padded = (_ceil_to(vol[0], tz), _ceil_to(vol[1], ty))
-                    cost = _segment_hbm_bytes(seg, padded, vol[2], 4, cost_widths, batch)
+                    nbytes = _segment_hbm_bytes(seg, padded, vol[2], 4, cost_widths, batch)
                     if i == 0:
-                        cost += batch * (
-                            math.prod(vol) * in_channels * inp
-                            + (padded[0] + 2 * h) * (padded[1] + 2 * h) * xp
-                            * rows(in_channels) * inp
-                        )
+                        nbytes += _in_bytes(seg, padded, vol, inp, batch)
                     if j == n:
-                        cost += batch * (
-                            padded[0] * padded[1] * xp * rows(seg.cout)
-                            + math.prod(vol) * seg.cout
-                        ) * act
+                        nbytes += _out_bytes(seg, padded, vol, act, batch)
+                    cost = nbytes if by_bytes else _segment_seconds(
+                        seg, padded, vol[2], nbytes, batch)
                     if top is None or cost < top[0]:
                         top = (cost, (tz, ty))
             if top is not None and top[0] + best[j] < best[i]:

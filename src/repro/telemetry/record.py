@@ -47,6 +47,11 @@ class TelemetryRecord:
     # inference (telemetry/traffic.py) — the TPU analogue of the paper
     # tracking texture bandwidth per backend.
     hbm_bytes_modeled: Optional[int] = None
+    # how far the served megakernel plan computes past the lane-padded
+    # volume (MegakernelPlan.recompute: 1.0 computes each voxel once) —
+    # None for the executors other than pallas_megakernel and
+    # pallas_fused.
+    recompute_modeled: Optional[float] = None
     # modeled inter-device (ICI) bytes of the run's halo exchanges — 0 for
     # single-device executors, the traffic.meshnet_collective_bytes model
     # for the sharded family (core/spatial_shard.py, DESIGN.md §2.2).

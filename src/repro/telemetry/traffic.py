@@ -198,10 +198,11 @@ def meshnet_megakernel_bytes(
 ) -> int:
     """Depth-first tiled megakernel: the planner's own traffic model
     (kernels/megakernel.py) — haloed tile reads per segment, one logits
-    write, zero intra-segment activation traffic. The plan is
-    re-optimized per precision (smaller working sets buy larger tiles)
-    AND per batch size (the DP scales data terms by N while charging the
-    weight stream once, so bigger batches favor halo-minimal tiles), and
+    write, zero intra-segment activation traffic — priced on the plan the
+    planner picks by modeled time. The plan is re-optimized per precision
+    (smaller working sets buy larger tiles) AND per batch size (the DP
+    scales data terms and computed positions by N while charging the
+    weight stream once), and
     each tensor role is priced at its policy width, including the int8
     input and staging streams under "int8w"."""
     pln = megakernel.plan_for_config(
