@@ -76,8 +76,7 @@ print(f"clean store, next lookup:  status={hit.status!r} "
 
 # --- act 2: the committed acceptance storm ------------------------------
 # fleet_cached is the golden scenario: every counter printed below is
-# asserted byte-exactly in tests/test_fleet_golden.py and gated in the
-# serving_cache section of BENCH_2.json.
+# asserted byte-exactly in tests/test_fleet_golden.py.
 rep = simulate_fleet(fleet_preset("fleet_cached"))
 s = rep.summary()
 req, c = s["requests"], s["cache"]

@@ -64,8 +64,7 @@ print(f"conserved={s['requests']['conserved']} — retries age in place: "
 
 # --- act 2: the committed acceptance storm ------------------------------
 # fleet_faultstorm is the golden scenario: every counter printed below
-# is asserted byte-exactly in tests/test_resilience.py and gated in the
-# serving_resilience section of BENCH_2.json.
+# is asserted byte-exactly in tests/test_resilience.py.
 rep = simulate_fleet(fleet_preset("fleet_faultstorm"))
 s = rep.summary()
 req, r = s["requests"], s["resilience"]

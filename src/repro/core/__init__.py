@@ -3,13 +3,11 @@ memory-constrained inference pipeline (patching / cropping / streaming /
 spatial sharding / connected components)."""
 
 from repro.core.meshnet import MeshNetConfig, PAPER_MODELS
-from repro.core.unet3d import UNet3DConfig
 from repro.core.pipeline import PipelineConfig, PipelineResult
 
 __all__ = [
     "MeshNetConfig",
     "PAPER_MODELS",
-    "UNet3DConfig",
     "PipelineConfig",
     "PipelineResult",
 ]

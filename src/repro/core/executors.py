@@ -61,8 +61,7 @@ Each spec also carries ``hbm_bytes`` — the modeled HBM traffic of one
 forward under that executor's schedule (telemetry/traffic.py) — and, for
 the sharded family, ``collective_bytes`` — the modeled inter-device halo
 bytes. The pipeline stamps both into every telemetry record
-(``hbm_bytes_modeled`` / ``collective_bytes_modeled``) and the benchmarks
-report them next to wall-clock.
+(``hbm_bytes_modeled`` / ``collective_bytes_modeled``).
 
 Extending: ``register(ExecutorSpec(...))`` adds a backend (e.g. a
 quantised or remote forward) without touching the pipeline, engine, or

@@ -168,32 +168,3 @@ def _streaming_apply_precision(
         + head["b"].astype(jnp.float32)
     )
     return logits.astype(adt)
-
-
-def streaming_apply_unrolled(params, x: jax.Array, cfg: MeshNetConfig) -> jax.Array:
-    """Variant without the padded-kernel trick: a Python loop over layers
-    with explicit buffer donation between steps via jit boundaries.
-
-    Closest to what Brainchop literally does (one WebGL program per layer,
-    dispose the previous tensor). Used for comparison in benchmarks; the
-    scan version is the production path.
-    """
-    if x.ndim == 4:
-        x = x[..., None]
-
-    @jax.jit
-    def run_first(layer, x):
-        out, _ = meshnet.apply_layer(layer, x, cfg.dilations[0], cfg, training=False)
-        return out
-
-    x = run_first(params["layers"][0], x)
-    for i, d in enumerate(cfg.dilations[1:], start=1):
-        # donate_argnums frees the previous activation as soon as the layer
-        # kernel has consumed it — the "strategic disposal".
-        step = jax.jit(
-            lambda layer, x, d=d: meshnet.apply_layer(layer, x, d, cfg, training=False)[0],
-            donate_argnums=(1,),
-        )
-        x = step(params["layers"][i], x)
-    head = params["head"]
-    return meshnet.dilated_conv3d(x, head["w"], head["b"], dilation=1)

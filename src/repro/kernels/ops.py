@@ -8,9 +8,8 @@ forward pass (`meshnet_apply`) that fuses conv+BN+ReLU per layer.
 ``meshnet_apply`` is the "pallas_fused" backend of the executor registry
 (core/executors.py); ``meshnet_apply_megakernel`` is the depth-first
 "pallas_megakernel" backend (kernels/megakernel.py) — the pipeline's
-production path on TPU when the tile plan fits VMEM ("auto" prefers it),
-benchmarked head-to-head against the XLA reference in
-benchmarks/bench_kernels.py. Parity with ``meshnet.apply`` (eval mode) is
+production path on TPU when the tile plan fits VMEM ("auto" prefers it).
+Parity with ``meshnet.apply`` (eval mode) is
 enforced by tests/test_executors.py and tests/test_megakernel.py across
 the PAPER_MODELS sweep.
 """

@@ -3,8 +3,7 @@
 Single pod: 16 x 16 = 256 chips, axes ("data", "model").
 Multi-pod: 2 x 16 x 16 = 512 chips, axes ("pod", "data", "model") — the
 "pod" axis composes with "data" for batch/FSDP sharding; gradient
-all-reduces cross the pod boundary (DCN in a real deployment; the
-collective roofline term prices it).
+all-reduces cross the pod boundary (DCN in a real deployment).
 
 Functions, not module constants: importing this module never touches jax
 device state (the dry-run sets XLA_FLAGS before any jax import).
@@ -38,8 +37,3 @@ def axis_size(mesh, axes) -> int:
         n *= mesh.shape[a]
     return n
 
-
-# v5e hardware constants for the roofline (per chip).
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link

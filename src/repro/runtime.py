@@ -1,5 +1,5 @@
 """Process set-up shared by the entry points (chip_smoke.py,
-benchmarks/run.py, launch/serve.py). Nothing here runs at import time:
+chipbench/, launch/serve.py). Nothing here runs at import time:
 each entry point calls ``enable_compile_cache`` once, before its first
 compile."""
 

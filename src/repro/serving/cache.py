@@ -23,7 +23,7 @@ an afterthought:
     counted in ``stats.quarantined`` — and the request transparently
     recomputes. Corrupt bytes can NEVER reach a completion:
     ``stats.quarantined_served`` counts serves of unverified bytes and is
-    guarded to stay 0 by tests and the BENCH gate.
+    guarded to stay 0 by tests and the Zipf soak.
   * **Single-flight stampede collapsing** — a miss registers an in-flight
     *pinned* placeholder; concurrent identical requests on the same
     replica attach to it as followers and complete with the leader's
@@ -151,7 +151,7 @@ class CacheConfig:
 @dataclasses.dataclass
 class CacheStats:
     """The cache's observable ledger — every counter the golden traces,
-    ``telemetry/analysis.cache_summary``, and the BENCH gate pin."""
+    ``telemetry/analysis.cache_summary``, and the soaks pin."""
 
     lookups: int = 0
     hits: int = 0
@@ -332,7 +332,7 @@ class ArtifactCache:
         the caller's breach path recomputes as a fresh miss, and no
         other lookup can keep hitting the corrupt bytes), and a typed
         error aborts the serve. The counter is pinned to 0 by tests and
-        the BENCH gate."""
+        the Zipf soak."""
         if not self._verified(entry):
             self.stats.quarantined_served += 1
             self._quarantine(entry)

@@ -1,10 +1,7 @@
 """Batched serving engine — the deployment-side counterpart of Brainchop's
-"serve a pre-trained model to whoever shows up" story, generalised to the
-architecture zoo.
+"serve a pre-trained model to whoever shows up" story.
 
-Two engines:
-
-SegmentationEngine — batches incoming MRI volumes and runs the Brainchop
+SegmentationEngine batches incoming MRI volumes and runs the Brainchop
 pipeline (conform -> crop -> MeshNet -> components), with the memory-budget
 guard choosing full-volume vs failsafe sub-volume mode per request —
 exactly the tool's client-side adaptation logic, server-side. Inference
@@ -28,229 +25,13 @@ signature-compatible requests. One engine == one fleet replica: the
 replicated serving tier (serving/fleet.py) builds N engines — each with
 its own jit caches and prepared weight pytrees — and routes across them
 by dispatch-signature cache affinity.
-
-LMEngine — continuous-batching text generation for any ModelConfig:
-chunked prefill (sequence patching, DESIGN.md §4), ring-buffer KV caches
-for sliding-window configs, greedy/temperature sampling, per-slot EOS
-retirement and slot reuse.
 """
 
 from __future__ import annotations
 
-import dataclasses
-import time
-from typing import Any, Callable, Optional
+from typing import Any
 
 import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.models import model as MD
-from repro.models.config import ModelConfig
-
-
-@dataclasses.dataclass
-class Request:
-    prompt: list[int]
-    max_new_tokens: int = 16
-    temperature: float = 0.0
-    id: int = 0
-
-
-@dataclasses.dataclass
-class Completion:
-    id: int
-    tokens: list[int]
-    prefill_s: float
-    decode_s: float
-
-
-class LMEngine:
-    """Static-slot continuous batching engine.
-
-    ``slots`` concurrent sequences share one cache; finished slots are
-    refilled from the queue. Prefill runs per-request in chunks of
-    ``prefill_chunk`` (compiled once per chunk shape); decode advances all
-    live slots in lock-step with a single compiled serve_step.
-    """
-
-    def __init__(
-        self,
-        params,
-        cfg: ModelConfig,
-        *,
-        slots: int = 4,
-        max_seq: int = 512,
-        prefill_chunk: int = 64,
-        eos_id: int | None = None,
-        rng: jax.Array | None = None,
-    ):
-        self.params = params
-        self.cfg = cfg
-        self.slots = slots
-        self.max_seq = max_seq
-        self.prefill_chunk = prefill_chunk
-        self.eos_id = eos_id
-        self.rng = rng if rng is not None else jax.random.PRNGKey(0)
-        self.cache = MD.init_cache(cfg, slots, max_seq)
-        self.pos = np.zeros((slots,), np.int32)  # per-slot next position
-        self.live = np.zeros((slots,), bool)
-
-        cfg_ = cfg
-
-        @jax.jit
-        def _decode(params, token, cache, pos):
-            logits, cache = MD.decode_step(params, token, cache, pos, cfg_)
-            return logits[:, -1], cache
-
-        self._decode = _decode
-
-    # --- prefill ------------------------------------------------------------
-
-    def _prefill_one(self, slot: int, prompt: list[int]) -> None:
-        """Feed a prompt token-by-token through decode_step (correct for
-        every family incl. recurrent states). Chunk-level batching of the
-        token loop is jit'd via lax.scan for throughput."""
-        cfg = self.cfg
-
-        @jax.jit
-        def run_chunk(params, tokens, cache, start):
-            def step(carry, tok):
-                cache, pos = carry
-                _, cache = MD.decode_step(params, tok[None, None], cache, pos, cfg)
-                return (cache, pos + 1), None
-
-            (cache, pos), _ = jax.lax.scan(step, (cache, start), tokens)
-            return cache, pos
-
-        # The engine cache is batched over slots; run the scan on a
-        # single-slot view then write it back.
-        one = jax.tree.map(lambda c: c[:, slot : slot + 1], self.cache)
-        pos = jnp.asarray(self.pos[slot], jnp.int32)
-        chunk = self.prefill_chunk
-        toks = np.asarray(prompt, np.int32)
-        for i in range(0, len(toks), chunk):
-            part = toks[i : i + chunk]
-            if len(part) < chunk:
-                pad = np.zeros((chunk - len(part),), np.int32)
-                padded = np.concatenate([part, pad])
-                # run the valid prefix only, step-by-step for the tail
-                for t in part:
-                    _, one = self._decode_single(one, int(t), int(pos))
-                    pos = pos + 1
-            else:
-                one, pos = run_chunk(self.params, jnp.asarray(part), one, pos)
-        self.cache = jax.tree.map(
-            lambda full, o: jax.lax.dynamic_update_slice_in_dim(full, o, slot, axis=1)
-            if full.ndim > 1
-            else full,
-            self.cache,
-            one,
-        )
-        self.pos[slot] = int(pos)
-
-    def _decode_single(self, one_cache, token: int, pos: int):
-        logits, cache = self._decode(
-            self.params, jnp.asarray([[token]], jnp.int32), one_cache, jnp.asarray(pos, jnp.int32)
-        )
-        return logits, cache
-
-    # --- main loop ------------------------------------------------------------
-
-    def run(self, requests: list[Request]) -> list[Completion]:
-        queue = list(requests)
-        active: dict[int, dict] = {}
-        done: list[Completion] = []
-
-        def admit():
-            for s in range(self.slots):
-                if not self.live[s] and queue:
-                    req = queue.pop(0)
-                    t0 = time.perf_counter()
-                    self.pos[s] = 0
-                    self._reset_slot(s)
-                    self._prefill_one(s, req.prompt[:-1])
-                    active[s] = {
-                        "req": req,
-                        "out": [],
-                        "next": req.prompt[-1],
-                        "prefill_s": time.perf_counter() - t0,
-                        "t0": time.perf_counter(),
-                    }
-                    self.live[s] = True
-
-        admit()
-        while active:
-            tokens = np.zeros((self.slots, 1), np.int32)
-            for s, st in active.items():
-                tokens[s, 0] = st["next"]
-            # lock-step decode: one compiled step for all slots. Each slot
-            # has its own position; decode_step takes a scalar pos, so we
-            # use the max and rely on per-slot ring indexing... positions
-            # differ across slots, so instead advance slots individually
-            # when their positions diverge, batched when aligned.
-            groups: dict[int, list[int]] = {}
-            for s in active:
-                groups.setdefault(int(self.pos[s]), []).append(s)
-            for pos, slot_ids in groups.items():
-                logits, new_cache = self._decode(
-                    self.params, jnp.asarray(tokens), self.cache, jnp.asarray(pos, jnp.int32)
-                )
-                # merge only the stepped slots' cache lanes back
-                mask = np.zeros((self.slots,), bool)
-                mask[slot_ids] = True
-                m = jnp.asarray(mask)
-
-                def merge(new, old):
-                    bdim = 1 if new.ndim > 1 else 0
-                    shape = [1] * new.ndim
-                    shape[bdim] = self.slots
-                    return jnp.where(m.reshape(shape), new, old) if new.shape[bdim] == self.slots else new
-
-                self.cache = jax.tree.map(merge, new_cache, self.cache)
-                lg = np.asarray(logits)
-                for s in slot_ids:
-                    st = active[s]
-                    if st["req"].temperature > 0:
-                        self.rng, k = jax.random.split(self.rng)
-                        nxt = int(
-                            jax.random.categorical(k, jnp.asarray(lg[s]) / st["req"].temperature)
-                        )
-                    else:
-                        nxt = int(np.argmax(lg[s]))
-                    st["out"].append(nxt)
-                    st["next"] = nxt
-                    self.pos[s] += 1
-                    if (
-                        len(st["out"]) >= st["req"].max_new_tokens
-                        or (self.eos_id is not None and nxt == self.eos_id)
-                        or self.pos[s] >= self.max_seq - 1
-                    ):
-                        done.append(
-                            Completion(
-                                id=st["req"].id,
-                                tokens=st["out"],
-                                prefill_s=st["prefill_s"],
-                                decode_s=time.perf_counter() - st["t0"],
-                            )
-                        )
-                        self.live[s] = False
-                        del active[s]
-            admit()
-        return sorted(done, key=lambda c: c.id)
-
-    def _reset_slot(self, s: int) -> None:
-        fresh = MD.init_cache(self.cfg, 1, self.max_seq)
-        self.cache = jax.tree.map(
-            lambda full, fr: jax.lax.dynamic_update_slice_in_dim(full, fr, s, axis=1)
-            if full.ndim > 1
-            else full,
-            self.cache,
-            fresh,
-        )
-
-
-# ---------------------------------------------------------------- MRI side ---
 
 
 class SegmentationEngine:

@@ -41,8 +41,7 @@ medical-image workloads:
 Everything runs on the shared ``VirtualClock``, so fleet p50/p99, shed
 counts, affinity hit rates, and the autoscaler's event timeline are pure
 functions of (code, seed): ``simulate_fleet`` summaries are byte-exact
-golden traces (tests/golden/fleet_*.json) and gated BENCH_2.json rows
-(``serving_fleet`` section, absolute tolerance). DESIGN.md §6.
+golden traces (tests/golden/fleet_*.json). DESIGN.md §6.
 """
 
 from __future__ import annotations
@@ -892,7 +891,7 @@ class FleetReport:
     arrived: int
 
     def summary(self) -> dict:
-        """The deterministic fleet rollup — golden-trace / BENCH payload:
+        """The deterministic fleet rollup — the golden-trace payload:
         counts and conservation (fleet + per replica), fleet-wide and
         per-class virtual-latency percentiles over ORIGINAL arrival
         times (failover latency includes the time lost to the dead
@@ -1123,7 +1122,7 @@ def simulate_fleet(
 def fleet_preset(
     name: str, seed: int = 0, horizon_s: Optional[float] = None
 ) -> FleetConfig:
-    """The four committed fleet scenarios (golden traces + BENCH rows):
+    """The four committed fleet scenarios (golden traces):
 
     ``fleet_steady``   — 3 replicas under 4x the single-server steady
                          rate: the horizontal-scale latency floor, and

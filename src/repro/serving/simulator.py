@@ -24,10 +24,10 @@ throughput / shed-rate number is **bit-reproducible in CI on CPU**:
     virtual clock. No wall-clock value enters any decision or summary.
 
 ``simulate`` returns a ``SimReport`` whose ``summary()`` dict (rounded,
-key-sorted) is what the golden-trace regression tests and the gated
-``serving`` rows of BENCH_2.json serialize — two runs with one seed are
-byte-identical, so scheduler behavior changes show up as review diffs,
-never as flakes. ``benchmarks/bench_serving.py`` is the CLI.
+key-sorted) is what the golden-trace regression tests serialize and the
+virtual-hour soaks (tests/test_serving_soak.py) assert on — two runs with
+one seed are byte-identical, so scheduler behavior changes show up as
+review diffs, never as flakes.
 """
 
 from __future__ import annotations
@@ -254,7 +254,7 @@ class SimReport:
         """The deterministic rollup: counts, conservation, and per-class
         virtual-latency percentiles (nearest-rank; rounded to fixed
         decimals so serialization is byte-stable). This dict IS the
-        golden-trace / BENCH_2.json payload."""
+        golden-trace payload."""
         st = self.scheduler.stats
         by_class: dict[str, list[Completion]] = {}
         for c in self.completions:
@@ -520,9 +520,9 @@ def simulate(engine, cfg: SimConfig) -> SimReport:
 def reference_engine():
     """The canonical engine the committed traces are generated against:
     a tiny CPU-friendly configuration (the simulator load-tests the
-    scheduler, not the kernels). Used by benchmarks/bench_serving.py,
-    the golden-trace tests, and the CI soak — all three MUST price
-    admission off the same model or the byte-identical claim breaks."""
+    scheduler, not the kernels). Used by the golden-trace tests and the
+    soaks — both MUST price admission off the same model or the
+    byte-identical claim breaks."""
     import jax
 
     from repro.core import meshnet
@@ -561,7 +561,7 @@ STANDARD_MIX = (
 
 
 def preset(name: str, seed: int = 0, horizon_s: Optional[float] = None) -> SimConfig:
-    """The three committed load scenarios (golden traces + BENCH rows):
+    """The three committed load scenarios (golden traces):
 
     ``steady``   — Poisson arrivals well under capacity: the queue stays
                    shallow, nothing sheds; the latency floor.
@@ -577,7 +577,7 @@ def preset(name: str, seed: int = 0, horizon_s: Optional[float] = None) -> SimCo
     ``SchedulerConfig.batched_dispatch=True`` — each dispatch group
     serves as ONE batched launch whose weight stream amortizes across
     the members. Comparing ``overload`` vs ``overload_batched`` on one
-    seed isolates the batching win (BENCH's ``batched`` section).
+    seed isolates the batching win.
     """
     if name.endswith("_batched"):
         cfg = preset(name[: -len("_batched")], seed=seed, horizon_s=horizon_s)
@@ -651,6 +651,3 @@ def preset(name: str, seed: int = 0, horizon_s: Optional[float] = None) -> SimCo
             service=ServiceModel(base_s=0.1, batch_overhead_s=0.05),
         )
     raise KeyError(f"unknown scenario preset {name!r}: steady | burst | overload")
-
-
-PRESETS = ("steady", "burst", "overload")

@@ -1,10 +1,6 @@
-"""Statistical analysis of telemetry — the paper's §IV toolkit.
-
-Re-implements, over simulated fleet telemetry, the analyses the paper runs
-over its 1336 browser sessions: success-rate contingency tables,
-Chi-square tests for independence, statistical power, and IPTW (inverse
-probability of treatment weighting) causal effect estimates for the
-patching / cropping / texture-size interventions.
+"""Rollups of serving telemetry: per-(executor, precision) cells,
+per-class latency and SLO attainment, per-replica ledgers, resilience
+and cache counters, over ``TelemetryRecord``s (telemetry/record.py).
 """
 
 from __future__ import annotations
@@ -13,78 +9,6 @@ import dataclasses
 import math
 
 import numpy as np
-from scipy import stats
-
-
-@dataclasses.dataclass
-class ContingencyResult:
-    table: np.ndarray  # 2x2 [treatment x outcome]
-    chi2: float
-    p_value: float
-    success_rate_treated: float
-    success_rate_control: float
-    power: float
-
-    def summary(self) -> str:
-        return (
-            f"chi2={self.chi2:.3f} p={self.p_value:.2e} "
-            f"SR(treated)={self.success_rate_treated*100:.2f}% "
-            f"SR(control)={self.success_rate_control*100:.2f}% power={self.power:.3f}"
-        )
-
-
-def contingency(treated_ok: int, treated_fail: int, control_ok: int, control_fail: int,
-                alpha: float = 0.05) -> ContingencyResult:
-    """Chi-square test for a 2x2 treatment/outcome table + power analysis
-    (the paper: power 0.963 at alpha 0.05 for the full dataset)."""
-    table = np.array([[treated_ok, treated_fail], [control_ok, control_fail]], float)
-    if (table.sum(0) == 0).any() or (table.sum(1) == 0).any():
-        # Degenerate margin (e.g. zero successes in both arms): no evidence.
-        tr = treated_ok / max(treated_ok + treated_fail, 1)
-        cr = control_ok / max(control_ok + control_fail, 1)
-        return ContingencyResult(table, 0.0, 1.0, tr, cr, 0.0)
-    chi2, p, _, _ = stats.chi2_contingency(table, correction=False)
-    n = table.sum()
-    w = math.sqrt(chi2 / n)  # effect size (phi)
-    # power of chi-square test with df=1 at this effect size and sample size
-    nc = n * w * w  # noncentrality
-    crit = stats.chi2.ppf(1 - alpha, df=1)
-    power = 1 - stats.ncx2.cdf(crit, df=1, nc=max(nc, 1e-9))
-    tr = treated_ok / max(treated_ok + treated_fail, 1)
-    cr = control_ok / max(control_ok + control_fail, 1)
-    return ContingencyResult(table, float(chi2), float(p), tr, cr, float(power))
-
-
-def iptw_ate(treatment: np.ndarray, outcome: np.ndarray, confounders: np.ndarray) -> float:
-    """IPTW Average Treatment Effect:
-        ATE = E[Y | do(T=1)] - E[Y | do(T=0)]
-    with propensity scores from a logistic regression of T on confounders
-    (fitted by Newton iterations — no sklearn dependency).
-    """
-    X = np.column_stack([np.ones(len(treatment)), confounders])
-    beta = np.zeros(X.shape[1])
-    for _ in range(50):
-        p = 1.0 / (1.0 + np.exp(-X @ beta))
-        W = p * (1 - p) + 1e-6
-        grad = X.T @ (treatment - p)
-        hess = (X * W[:, None]).T @ X + 1e-6 * np.eye(X.shape[1])
-        step = np.linalg.solve(hess, grad)
-        beta += step
-        if np.abs(step).max() < 1e-8:
-            break
-    p = np.clip(1.0 / (1.0 + np.exp(-X @ beta)), 1e-3, 1 - 1e-3)
-    w1 = treatment / p
-    w0 = (1 - treatment) / (1 - p)
-    ate = (w1 * outcome).sum() / w1.sum() - (w0 * outcome).sum() / w0.sum()
-    return float(ate)
-
-
-def regression_adjustment(treatment, outcome, confounders) -> float:
-    """OLS effect of treatment on outcome controlling for confounders
-    (the paper's 'regression adjustment' patching estimate)."""
-    X = np.column_stack([np.ones(len(treatment)), treatment, confounders])
-    coef, *_ = np.linalg.lstsq(X, outcome, rcond=None)
-    return float(coef[1])
 
 
 @dataclasses.dataclass

@@ -5,8 +5,8 @@ The paper's fail taxonomy (Table V): "Failed to compile fragment shader",
 resource-limit manifestations. On TPU the corresponding wall is HBM bytes
 per device (and VMEM per kernel block). This module prices each inference
 strategy's peak working set against a configurable budget, so the
-benchmark harness can re-run the paper's interventions (patching, cropping,
-texture size) as budget sweeps and regenerate Tables V–VIII.
+engine's guard can choose full-volume or failsafe sub-volume mode per
+request, as the paper's patching and cropping interventions do.
 
 The budget model is deliberately analytic (bytes, not wall-clock): it is
 the part of the paper we *simulate* because the actual gate (a fleet of
@@ -23,16 +23,7 @@ if TYPE_CHECKING:  # annotation-only: a runtime import would cycle back
     # through repro.core (core.pipeline imports this module).
     from repro.core.meshnet import MeshNetConfig
 
-# Browser-era texture sizes map to working-set budgets; TPU-era ladder:
 V5E_HBM_BYTES = 16 * 1024**3  # 16 GB HBM per v5e chip
-WEBGL_LIKE_BUDGETS = {
-    # texture_size -> approx usable bytes (texture^2 * 4 bytes RGBA)
-    8192: 8192**2 * 4,  # 256 MiB
-    9159: 9159**2 * 4,
-    13585: 13585**2 * 4,
-    16384: 16384**2 * 4,  # 1 GiB
-    32768: 32768**2 * 4,  # 4 GiB
-}
 
 
 class BudgetExceeded(Exception):
@@ -53,10 +44,6 @@ class MemoryBudget:
     @staticmethod
     def unlimited() -> "MemoryBudget":
         return MemoryBudget(bytes_limit=1 << 62, name="unlimited")
-
-    @staticmethod
-    def from_texture_size(tex: int) -> "MemoryBudget":
-        return MemoryBudget(WEBGL_LIKE_BUDGETS[tex], name=f"texture_{tex}")
 
     @staticmethod
     def v5e() -> "MemoryBudget":

@@ -1,9 +1,8 @@
 """Telemetry records — the schema of the paper's Tables III/IV.
 
 Brainchop collects anonymized per-run telemetry (stage timings, model,
-status, failure type). We keep the same columns so the analysis code in
-telemetry/analysis.py can regenerate the paper's contingency tables from
-simulated runs.
+status, failure type). We keep the same columns, and the serving
+rollups in telemetry/analysis.py read them.
 """
 
 from __future__ import annotations

@@ -7,8 +7,7 @@ because MeshNet's narrow models are memory-bound (DESIGN.md §2) — that
 byte count *is* the performance model. These functions price it
 analytically (bytes, not wall-clock), the same methodology as the memory
 budget model in telemetry/budget.py: the numbers drive the DESIGN.md §2
-traffic table, the ``traffic`` benchmark section, the ``BENCH_2.json``
-perf trajectory, and the per-run ``hbm_bytes_modeled`` telemetry field.
+traffic table and the per-run ``hbm_bytes_modeled`` telemetry field.
 
 Modeling conventions (counted per forward):
   * every XLA op materialises its output: a pad is a read + padded write,
@@ -94,25 +93,6 @@ def meshnet_xla_bytes(
         cin = c
     data += v * (c + cfg.num_classes) * ab  # 1x1x1 head
     return batch * data + weights
-
-
-def dilated_conv_layer_bytes(
-    vol: Shape3,
-    cin: int,
-    cout: int,
-    dilation: int,
-    dtype_bytes: int = 4,
-    weight_dtype_bytes: int | None = None,
-) -> int:
-    """One fused haloed-load conv call (kernels/dilated_conv3d.py), priced
-    by the one-layer plan that call runs — the conversion into the padded
-    plane layout, one haloed window DMA per output tile (+ the streamed
-    weights at their own width), the fused write, and the conversion back
-    out. The kernels benchmark prices single conv rows with it."""
-    widths = (dtype_bytes, weight_dtype_bytes or dtype_bytes, dtype_bytes, dtype_bytes)
-    return megakernel.plan(
-        (dilation,), cin, cout, 0, tuple(int(v) for v in vol), widths=widths
-    ).hbm_bytes()
 
 
 def meshnet_fused_bytes(

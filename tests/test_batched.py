@@ -208,8 +208,8 @@ class TestBatchedTraffic:
 
     def test_plan_hbm_bytes_batch1_identity(self):
         # the headline bugfix regression: hbm_bytes(batch=1) must equal
-        # the committed single-volume number (BENCH batch-1 rows are
-        # byte-identical), and the traffic facade must agree with the plan
+        # the single-volume number, and the traffic facade must agree
+        # with the plan
         cfg = PAPER_MODELS["gwm_light"]
         pln = megakernel.plan_for_config(cfg, (256, 256, 256))
         assert pln.hbm_bytes() == pln.hbm_bytes(batch=1)
@@ -358,8 +358,8 @@ class TestBatchedDispatch:
         assert a["latency_ms"] == b["latency_ms"]
 
     def test_overload_batched_conserves_and_moves_the_cliff(self):
-        # the BENCH acceptance in miniature: same seed/trace, batching
-        # on, conservation exact and the overload p99 no worse
+        # same seed/trace, batching on: conservation exact and the
+        # overload p99 no worse
         base = simulate(
             reference_engine(), preset("overload", seed=0, horizon_s=150.0)
         ).summary()

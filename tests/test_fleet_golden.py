@@ -6,15 +6,17 @@ JSON summaries (tests/golden/fleet_*.json). The fleet simulator is
 bit-deterministic end to end (one virtual clock across N replicas,
 seeded arrivals/mix, modeled service + cold-compile), so router,
 failover, or autoscaler behavior changes show up here as reviewable
-golden diffs, never as flakes. Regenerate with:
+golden diffs, never as flakes. Regenerate a golden (ONLY on intentional
+behavior change) from this file's own ``_fresh_summary``:
 
-    PYTHONPATH=src python -m benchmarks.bench_serving --fleet --seed 0 \
-        --json-out /tmp/fleet.json
-    # then split per scenario into tests/golden/fleet_<name>.json
+    PYTHONPATH=src python -c "
+    import json, sys; sys.path.insert(0, 'tests')
+    from test_fleet_golden import FLEET_SCENARIOS, _fresh_summary
+    for n in FLEET_SCENARIOS + ['fleet_cached']:
+        s = json.dumps(_fresh_summary(n), indent=1, sort_keys=True)
+        open(f'tests/golden/{n}.json', 'w').write(s + '\\n')"
 
-The same numbers feed the gated ``serving_fleet`` section of
-BENCH_2.json, so the goldens and the bench baseline must move together
-in one PR. The semantic tests below pin what each golden must *show* —
+The semantic tests below pin what each golden must *show* —
 the acceptance claims of the fleet tier — so a regenerated golden that
 silently stopped exercising failover or autoscaling fails review here.
 """

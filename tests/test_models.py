@@ -236,29 +236,3 @@ class TestFamilyInvariants:
         for arch, (lo, hi) in expect.items():
             n = configs.get(arch).param_counts()["total"]
             assert lo <= n <= hi, (arch, n)
-
-
-class TestServingEngine:
-    def test_batched_engine_matches_manual_greedy(self):
-        from repro.serving.engine import LMEngine, Request
-
-        cfg = dataclasses.replace(configs.get_smoke("tinyllama-1.1b"), dtype=jnp.float32)
-        params = MD.init(KEY, cfg)
-        eng = LMEngine(params, cfg, slots=2, max_seq=48, prefill_chunk=4)
-        reqs = [Request(prompt=[1, 2, 3, 4, 5], max_new_tokens=5, id=i) for i in range(3)]
-        outs = eng.run(reqs)
-        assert len(outs) == 3
-        assert outs[0].tokens == outs[1].tokens == outs[2].tokens  # same prompt
-        # manual reference
-        cache = MD.init_cache(cfg, 1, 48)
-        pos = 0
-        for t in [1, 2, 3, 4]:
-            _, cache = MD.decode_step(params, jnp.asarray([[t]], jnp.int32), cache, jnp.asarray(pos, jnp.int32), cfg)
-            pos += 1
-        cur, manual = 5, []
-        for _ in range(5):
-            lg, cache = MD.decode_step(params, jnp.asarray([[cur]], jnp.int32), cache, jnp.asarray(pos, jnp.int32), cfg)
-            cur = int(jnp.argmax(lg[0, -1]))
-            manual.append(cur)
-            pos += 1
-        assert outs[0].tokens == manual

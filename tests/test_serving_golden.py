@@ -4,15 +4,18 @@ Three committed simulator traces — steady, burst, overload — asserted
 EXACTLY against checked-in JSON summaries (tests/golden/serving_*.json).
 The simulator is bit-deterministic (virtual clock, seeded arrivals,
 modeled service), so any scheduler-behavior change shows up here as a
-reviewable golden diff instead of a silent drift; regenerate with:
+reviewable golden diff instead of a silent drift. Regenerate a golden
+(ONLY on intentional behavior change) from this file's own
+``_fresh_summary``:
 
-    PYTHONPATH=src python -m benchmarks.bench_serving --seed 0 \
-        --json-out /tmp/serving.json
-    # then split per scenario into tests/golden/serving_<name>.json
+    PYTHONPATH=src python -c "
+    import json, sys; sys.path.insert(0, 'tests')
+    from test_serving_golden import _fresh_summary
+    for n in ('steady', 'burst', 'overload'):
+        s = json.dumps(_fresh_summary(n), indent=1, sort_keys=True)
+        open(f'tests/golden/serving_{n}.json', 'w').write(s + '\\n')"
 
-(or just update the failing file with the printed fresh summary). The
-same numbers feed the gated ``serving`` section of BENCH_2.json, so the
-golden and the bench baseline must move together in one PR.
+(or just update the failing file with the printed fresh summary).
 """
 
 import json

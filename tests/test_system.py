@@ -56,29 +56,6 @@ class TestMeshNet:
         )
 
 
-class TestUNetBaseline:
-    def test_forward_shape_preserving(self):
-        from repro.core import unet3d
-
-        cfg = unet3d.UNet3DConfig(base_channels=4, levels=2)
-        p = unet3d.init(KEY, cfg)
-        x = jax.random.normal(KEY, (1, 16, 16, 16))
-        out = unet3d.apply(p, x, cfg)
-        assert out.shape == (1, 16, 16, 16, cfg.num_classes)
-        assert bool(jnp.isfinite(out).all())
-
-    def test_grad_flows(self):
-        from repro.core import unet3d
-        from repro.training import losses as L
-
-        cfg = unet3d.UNet3DConfig(base_channels=4, levels=2)
-        p = unet3d.init(KEY, cfg)
-        x = jax.random.normal(KEY, (1, 8, 8, 8))
-        lab = jnp.zeros((1, 8, 8, 8), jnp.int32)
-        g = jax.grad(lambda p: L.segmentation_loss(unet3d.apply(p, x, cfg), lab, 3)[0])(p)
-        assert all(bool(jnp.isfinite(l).all()) for l in jax.tree.leaves(g))
-
-
 class TestPatching:
     def test_subvolume_inference_exact_in_interior(self):
         """Failsafe mode with overlap >= RF radius is numerically exact for
